@@ -16,6 +16,10 @@ the shared keys are merged on top. ``--quick`` exports ``BENCH_QUICK=1``,
 which quick-aware modules honor. Roofline/dry-run tables are separate
 (launch/dryrun.py produces them; benchmarks/roofline.py formats them) because
 they need the 512-device host platform, which the benches must NOT inherit.
+
+Each module runs in a child process of its own, one at a time, and this
+parent never imports JAX: a process that has touched JAX holds the chip, and
+a child that needs it would then fail or hang.
 """
 from __future__ import annotations
 
@@ -26,12 +30,13 @@ import os
 import subprocess
 import sys
 import time
-import traceback
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 for _p in (REPO, os.path.join(REPO, "src")):
     if _p not in sys.path:
         sys.path.insert(0, _p)
+
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 # (name, module, paper anchor) — the json file is BENCH_<name>.json
 MODULES = [
@@ -56,18 +61,32 @@ def git_sha():
         return None
 
 
+def run_child(modpath: str) -> None:
+    """Child side: run one bench module and hand its rows to the parent as
+    the last line of stdout."""
+    mod = importlib.import_module(modpath)
+    rows = [(n, float(us), str(d)) for n, us, d in mod.run()]
+    print(json.dumps(rows), flush=True)
+
+
 def run_module(name: str, modpath: str, anchor: str, sha) -> bool:
-    """Run one bench module, print its CSV, stamp its BENCH json. Returns
-    success."""
+    """Run one bench module in its own process, print its CSV, stamp its
+    BENCH json. Returns success."""
+    if "jax" in sys.modules:
+        raise RuntimeError("benchmarks/run.py imported JAX in its parent; a "
+                           "child would then find the chip held")
     json_path = os.path.join(REPO, f"BENCH_{name}.json")
     t0 = time.perf_counter()
-    try:
-        mod = importlib.import_module(modpath)
-        rows = [(n, float(us), str(d)) for n, us, d in mod.run()]
-    except Exception:  # noqa: BLE001 — a failed bench is a recorded failure
-        print(f"# {name}({anchor}) FAILED:\n{traceback.format_exc()}",
+    proc = subprocess.run(
+        [sys.executable, os.path.abspath(__file__), "--child", modpath],
+        cwd=REPO, stdout=subprocess.PIPE, text=True)
+    out = proc.stdout.rstrip("\n").split("\n")
+    print("\n".join(out[:-1]), flush=True)
+    if proc.returncode != 0:
+        print(f"# {name}({anchor}) FAILED (exit {proc.returncode})",
               flush=True)
         return False
+    rows = [tuple(r) for r in json.loads(out[-1])]
     wall = time.perf_counter() - t0
     for n, us, derived in rows:
         print(f"{n},{us:.1f},{derived}", flush=True)
@@ -101,10 +120,15 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="comma-separated module names "
                          f"({', '.join(n for n, _, _ in MODULES)})")
+    ap.add_argument("--child", default=None, help=argparse.SUPPRESS)
     args = ap.parse_args()
+    if args.child:
+        run_child(args.child)
+        return
     if args.quick:
         os.environ["BENCH_QUICK"] = "1"
     os.chdir(REPO)    # module-written BENCH_*.json land at the repo root
+    enable_compile_cache()     # through the environment, for the children
 
     work = MODULES
     if args.only:

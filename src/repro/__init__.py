@@ -1,9 +1,1 @@
-"""Peacock reproduction (arXiv:1405.4402) on a jax/Pallas TPU mapping.
-
-Importing any ``repro`` subpackage installs the jax version shims first, so
-the modern-API call sites (jax.shard_map / AxisType / pcast) work on the
-pinned older runtime too. See repro._compat.
-"""
-from repro import _compat as _compat
-
-_compat.install()
+"""Peacock reproduction (arXiv:1405.4402) on a jax/Pallas TPU mapping."""

@@ -26,12 +26,14 @@ Two mechanisms, exactly as in the paper:
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.scipy.special import digamma
+
+from repro.core import lda, sparse
 
 
 # ---------------------------------------------------------------------------
@@ -42,15 +44,16 @@ from jax.scipy.special import digamma
 def topic_count_histogram(doc_ids, z, valid, n_docs: int, n_topics: int,
                           max_count: int = 64):
     """Ω_kn for n in [1, max_count) — counts above the cap are clipped into the
-    last bin (their digamma increments are nearly identical there)."""
-    theta = jnp.zeros((n_docs, n_topics), jnp.int32).at[doc_ids, z].add(
-        valid.astype(jnp.int32))
-    clipped = jnp.minimum(theta, max_count - 1)
-    omega = jax.vmap(
-        lambda col: jnp.zeros((max_count,), jnp.int32).at[col].add(1),
-        in_axes=1, out_axes=0,
-    )(clipped)                                   # [K, max_count]
-    return omega.at[:, 0].set(0)                 # n = 0 contributes nothing
+    last bin (their digamma increments are nearly identical there).
+
+    Built from the sparse (doc, topic, count) pairs of one sort pass, never
+    from a dense [n_docs, K] Θ: O(T log T) work and O(T + K) memory."""
+    d = jnp.where(valid, doc_ids, n_docs)
+    _, topic, count, active = sparse.segment_totals(
+        d, z, valid.astype(jnp.int32), n_docs)
+    n = jnp.minimum(count, max_count - 1)
+    return jnp.zeros((n_topics, max_count), jnp.int32).at[topic, n].add(
+        active.astype(jnp.int32))
 
 
 @functools.partial(jax.jit, static_argnames=("max_len",))
@@ -89,18 +92,54 @@ def optimize_alpha(alpha, omega, doc_len_hist, n_iters: int = 20,
 # L1 topic clustering
 # ---------------------------------------------------------------------------
 
-def pairwise_l1(phi, beta, block: int = 512) -> np.ndarray:
-    """Pairwise L1 distance between normalized topic columns; blocked over K."""
-    pvk = np.asarray(phi, np.float64) + float(beta)
-    pvk = pvk / pvk.sum(axis=0, keepdims=True)      # [V, K]
+class L1Scan(NamedTuple):
+    """One blocked pairwise-L1 pass over the normalized topic columns,
+    reduced to what dedup consumes — never the K×K matrix itself."""
+
+    nearest: np.ndarray   # [K] f32 — each topic's smallest L1 to another topic
+    pairs: np.ndarray     # [E, 2] int32 — (i, j), i < j, with L1 < threshold
+    threshold: float
+
+
+@functools.partial(jax.jit, static_argnames=("block", "n_topics"))
+def _l1_row_block(pvk, start, threshold, block: int, n_topics: int):
+    """L1 distances from topic columns [start, start + block) of ``pvk``
+    [V, Kp] to every column, reduced on the device: (nearest [block],
+    number of below-threshold pairs (i < j), the bit-packed pair mask)."""
+    rows = jax.lax.dynamic_slice_in_dim(pvk, start, block, axis=1)  # [V, B]
+    # reduced over the leading (vocab) axis: no [V, B, Kp] intermediate
+    d = jnp.sum(jnp.abs(rows[:, :, None] - pvk[:, None, :]), axis=0)
+    row = start + jnp.arange(block)[:, None]
+    col = jnp.arange(pvk.shape[1])[None, :]
+    d = jnp.where((col == row) | (col >= n_topics), jnp.inf, d)
+    below = (d < threshold) & (col > row) & (row < n_topics)
+    return (jnp.min(d, axis=1), jnp.sum(below, dtype=jnp.int32),
+            jnp.packbits(below, axis=1))
+
+
+def l1_scan(phi, beta, threshold: float, block: int = 512) -> L1Scan:
+    """Pairwise L1 between normalized topic columns, in blocks of ``block``
+    topics on the device: each topic's nearest distance plus the pairs below
+    ``threshold``. Device memory is O(V·K + block·K); a block's pair mask
+    leaves the device only when it holds a pair."""
+    pvk = lda.phi_hat(jnp.asarray(phi), jnp.float32(beta))      # [V, K]
     K = pvk.shape[1]
-    out = np.zeros((K, K), np.float32)
-    for i in range(0, K, block):
-        a = pvk[:, i:i + block]
-        for j in range(0, K, block):
-            b = pvk[:, j:j + block]
-            out[i:i + block, j:j + block] = np.abs(a[:, :, None] - b[:, None, :]).sum(axis=0)
-    return out
+    block = min(block, K)
+    pvk = jnp.pad(pvk, ((0, 0), (0, (-K) % block)))
+    nearest = np.empty(pvk.shape[1], np.float32)
+    pairs = []
+    for start in range(0, K, block):
+        near, n_below, packed = _l1_row_block(
+            pvk, start, jnp.float32(threshold), block, K)
+        nearest[start:start + block] = np.asarray(near)
+        if int(n_below):
+            below = np.unpackbits(np.asarray(packed), axis=1,
+                                  count=pvk.shape[1]).astype(bool)
+            i, j = np.nonzero(below)
+            pairs.append(np.stack([start + i, j], axis=1))
+    pairs = (np.concatenate(pairs).astype(np.int32) if pairs
+             else np.zeros((0, 2), np.int32))
+    return L1Scan(nearest[:K], pairs, float(threshold))
 
 
 class _UnionFind:
@@ -120,20 +159,23 @@ class _UnionFind:
 
 
 def cluster_topics(phi, beta, l1_threshold: float,
-                   dist: np.ndarray | None = None) -> Tuple[np.ndarray, int]:
+                   scan: L1Scan | None = None) -> Tuple[np.ndarray, int]:
     """Merge topics with L1 distance below threshold.
 
     Returns (cluster_of_topic [K], n_clusters). Lower threshold ⇒ fewer merges;
     the paper prunes 10⁶ → ~10⁵ topics this way (Fig. 7B).
 
-    ``dist`` may carry a precomputed ``pairwise_l1`` matrix so callers that
+    ``scan`` may carry an :func:`l1_scan` at this threshold so callers that
     also need ``duplicate_fraction`` pay the O(K²V) distance pass once.
     """
-    d = pairwise_l1(phi, beta) if dist is None else np.asarray(dist)
-    K = d.shape[0]
+    if scan is None:
+        scan = l1_scan(phi, beta, l1_threshold)
+    elif scan.threshold != l1_threshold:
+        raise ValueError(f"scan was taken at L1 < {scan.threshold}, "
+                         f"not the merge threshold {l1_threshold}")
+    K = scan.nearest.shape[0]
     uf = _UnionFind(K)
-    ii, jj = np.where((d < l1_threshold) & (np.triu(np.ones_like(d), 1) > 0))
-    for a, b in zip(ii, jj):
+    for a, b in scan.pairs:
         uf.union(int(a), int(b))
     roots = np.array([uf.find(k) for k in range(K)])
     _, cluster_of = np.unique(roots, return_inverse=True)
@@ -154,11 +196,12 @@ def merge_topics(phi, psi, alpha, cluster_of: np.ndarray, n_clusters: int):
 
 
 def duplicate_fraction(phi, beta, l1_threshold: float = 0.5,
-                       dist: np.ndarray | None = None) -> float:
+                       scan: L1Scan | None = None) -> float:
     """Fraction of topics that have at least one duplicate (paper: 20–40% at 10⁵).
 
-    Accepts a precomputed ``pairwise_l1`` matrix via ``dist`` (not mutated).
+    Accepts an :func:`l1_scan` via ``scan`` (any threshold: it reads only
+    the nearest distances).
     """
-    d = pairwise_l1(phi, beta) if dist is None else np.array(dist, copy=True)
-    np.fill_diagonal(d, np.inf)
-    return float((d.min(axis=0) < l1_threshold).mean())
+    if scan is None:
+        scan = l1_scan(phi, beta, l1_threshold)
+    return float((scan.nearest < l1_threshold).mean())

@@ -45,8 +45,20 @@ def hash_bits(seed, a, b):
 
 def uniform01(seed, a, b):
     """Uniform in (0, 1): top 24 bits of the hash, offset to avoid exact 0."""
-    bits = hash_bits(seed, a, b) >> 8
-    return (bits.astype(jnp.float32) + 0.5) * jnp.float32(1.0 / (1 << 24))
+    return unit_interval(hash_bits(seed, a, b) >> 8)
+
+
+def unit_interval(bits):
+    """24-bit integers → (bits + 0.5) / 2**24, strictly inside (0, 1)."""
+    # through int32: Mosaic has no uint32 -> float32 cast; exact, since the
+    # bits are below 2**24
+    u = (bits.astype(jnp.int32).astype(jnp.float32) + 0.5) * jnp.float32(
+        1.0 / (1 << 24))
+    # the top value rounds to exactly 1.0 in float32 (2**24 - 0.5 is not
+    # representable), where gumbel() would return +inf: one draw in 2**24,
+    # a topic that wins regardless of the posterior about once in 170
+    # tokens at K = 10⁵
+    return jnp.minimum(u, jnp.float32(1.0 - 2.0 ** -24))
 
 
 def gumbel(seed, a, b):

@@ -60,7 +60,7 @@ def suggest_cap(doc_lengths, n_topics: int) -> int:
 # ------------------------------------------------- sorted-segment helper ----
 
 
-def _segment_totals(d, k, delta, n_docs: int):
+def segment_totals(d, k, delta, n_docs: int):
     """Aggregate per-(d, k) net deltas via one lexsort.
 
     Returns (ds, ks, tot, active): sorted doc/topic ids, the inclusive
@@ -111,7 +111,7 @@ def pairs_from_assignments(d, z, valid, n_docs: int, cap: int):
     order (the segments come out of a lexsort).
     """
     d_s = jnp.where(valid, d, n_docs)
-    ds, ks, tot, active = _segment_totals(
+    ds, ks, tot, active = segment_totals(
         d_s, z, valid.astype(jnp.int32), n_docs)
     rank = _doc_rank(ds, active)
     row = jnp.where(active, ds, n_docs)
@@ -165,7 +165,7 @@ def apply_deltas(topic, count, d, z_old, z_new, valid):
     kk = jnp.concatenate([z_old, z_new])
     sgn = jnp.concatenate(
         [-changed.astype(jnp.int32), changed.astype(jnp.int32)])
-    ds, ks, tot, active = _segment_totals(dd, kk, sgn, D)
+    ds, ks, tot, active = segment_totals(dd, kk, sgn, D)
     row_ix = jnp.where(ds < D, ds, 0)
 
     # ---- pass 1: net-negative deltas; free zeroed slots ----------------

@@ -17,12 +17,12 @@ against the true collapsed posterior ratio — reading O(k_d + n_mh) table
 entries per token instead of streaming K-wide VMEM tiles like the dense
 ``kernels/gibbs`` plane scan.
 
-Capacity note: tables and count planes are bound as whole-array VMEM blocks,
-which is exact at CI/interpret scale and correct-by-construction on TPU up to
-VMEM capacity (~16 MB/core → rows·K ≲ 1M table entries per shard). The
-production-scale variant keeps tables in HBM and DMAs per-probe rows — the
-dispatch seam in ``ops.py`` is where that lands; CI exercises these kernels
-under ``interpret=True`` bitwise against ``ref.py``.
+Capacity note: tables and count planes are bound as whole-array VMEM blocks
+(~16 MB/core → rows·K ≲ 1M table entries per shard), and the scalar gathers
+at dynamic lane offsets (``_get``) plus the MH kernel's ``cumsum`` are refused
+by the TPU compiler. So these kernels run only under ``interpret=True`` (CI,
+bitwise against ``ref.py``) or when forced; on TPU the dispatch in ``ops.py``
+runs ``ref.py`` under XLA (``ops.TPU_MODE``).
 
 HBM-resident tables (DESIGN.md §10, design gated on TPU): word-sharded
 model parallelism already divides rows·K per device by the slice count P, and
@@ -95,10 +95,10 @@ def _alias_build_kernel(ns_ref, wn_ref, order_ref, prob_ref, alias_ref,
         slot_safe = jnp.maximum(slot, 0)
         old_p = _get(prob_ref, 0, slot_safe)
         old_a = _get(alias_ref, 0, slot_safe)
-        pl.store(prob_ref, (pl.ds(0, 1), pl.ds(slot_safe, 1)),
-                 jnp.where(do_write, val, old_p).reshape(1, 1))
-        pl.store(alias_ref, (pl.ds(0, 1), pl.ds(slot_safe, 1)),
-                 jnp.where(do_write, ali, old_a).reshape(1, 1))
+        prob_ref[pl.ds(0, 1), pl.ds(slot_safe, 1)] = jnp.where(
+            do_write, val, old_p).reshape(1, 1)
+        alias_ref[pl.ds(0, 1), pl.ds(slot_safe, 1)] = jnp.where(
+            do_write, ali, old_a).reshape(1, 1)
 
         curw2 = jnp.where(use_small, curw - (1.0 - sw), curw)
         demote = jnp.logical_and(use_small, curw2 < 1.0)
@@ -237,8 +237,7 @@ def _mh_kernel(
             acc = u_acc < ratio
             s = jnp.where(acc, t_prop, s)
             p_s = jnp.where(acc, p_t, p_s)
-        pl.store(out_ref, (pl.ds(i, 1), pl.ds(0, 1)),
-                 s.astype(jnp.int32).reshape(1, 1))
+        out_ref[pl.ds(i, 1), pl.ds(0, 1)] = s.astype(jnp.int32).reshape(1, 1)
         return _
 
     jax.lax.fori_loop(0, block_t, token, 0)

@@ -2,8 +2,9 @@
 
 Same contract as ``kernels/gibbs/ops.py``: ``force`` in {None, "pallas",
 "interpret", "ref"}; None defers to the pinned process default
-(``repro.kernels.set_kernel_mode``) and then the cached backend probe, so CPU
-CI runs the exact jnp oracle and TPU runs the compiled kernel.
+(``repro.kernels.set_kernel_mode``) and then to the backend: both CPU and TPU
+run the jnp implementations (``ref.py``), compiled by XLA. On TPU that is
+:data:`TPU_MODE`, for the reason given there.
 
 ``build_alias`` normalizes + partitions ONCE here (``_prepare``) and hands
 identical inputs to whichever sweep implementation runs — ref vs kernel
@@ -27,6 +28,21 @@ from repro.kernels.alias.ref import build_alias_ref, mh_resample_ref
 # decorrelates the MH uniform stream from the dense sampler's Gumbel stream
 MH_SALT = 0x5EED_A11A
 
+# What the alias ops run on a TPU when nothing is forced or pinned. The Pallas
+# kernels bind whole [rows, K] tables as VMEM blocks (rows·K ≲ 10⁶ entries,
+# against 821 × 10⁵ for one chip's share of the paper deployment) and gather
+# single lanes at dynamic offsets, which Mosaic refuses; the MH kernel also
+# needs a cumsum that has no TPU lowering. So on TPU the jnp implementations
+# run under XLA. The rule is the backend's alone: nothing is caught and
+# nothing falls back at run time, and ``force="pallas"`` still reaches the
+# kernel (and the compiler's error). The HBM-resident kernel is ROADMAP 1d.
+TPU_MODE = "ref"
+
+
+def dispatch_mode(force: str | None = None) -> str:
+    """The implementation the alias ops run for ``force`` on this backend."""
+    return kernels_mod.kernel_mode(force, tpu=TPU_MODE)
+
 
 def _prepare(weights):
     """Mean-1 normalization + stable small/large partition of [R, K] rows.
@@ -40,9 +56,17 @@ def _prepare(weights):
                         jnp.float32(1e-30))
     wn = (weights * (jnp.float32(K) / total)).astype(jnp.float32)
     is_large = wn >= 1.0
-    order = jnp.argsort(is_large.astype(jnp.int32), axis=-1,
-                        stable=True).astype(jnp.int32)
-    ns = jnp.sum(~is_large, axis=-1).astype(jnp.int32)
+    small = ~is_large
+    ns = jnp.sum(small, axis=-1, dtype=jnp.int32)
+    # the stable argsort of is_large, from two prefix sums instead of a sort
+    # (a K = 10⁵ sort costs the TPU compiler ~15 s and every rebuild a sort)
+    pos = jnp.where(small, jnp.cumsum(small, axis=-1, dtype=jnp.int32),
+                    ns[:, None] + jnp.cumsum(is_large, axis=-1,
+                                             dtype=jnp.int32)) - 1
+    slots = jnp.arange(K, dtype=jnp.int32)
+    # row by row: one [R, K] scatter costs the TPU compiler ~25 s at
+    # R·K ≈ 10⁸, a loop of [K] scatters about 2 s
+    order = jax.lax.map(lambda p: jnp.zeros_like(p).at[p].set(slots), pos)
     return wn, order, ns
 
 
@@ -57,7 +81,7 @@ def build_alias(weights, *, force: str | None = None):
     lead = weights.shape[:-1]
     K = weights.shape[-1]
     wn, order, ns = _prepare(weights.reshape(-1, K).astype(jnp.float32))
-    mode = kernels_mod.kernel_mode(force)
+    mode = dispatch_mode(force)
     if mode == "pallas":
         prob, alias = alias_build_pallas(wn, order, ns)
     elif mode == "interpret":
@@ -90,7 +114,7 @@ def mh_resample(
     seed2 = prng.fmix32(jnp.asarray(seed, jnp.uint32)
                         ^ jnp.uint32(MH_SALT))
     alpha_sum = jnp.sum(alpha).astype(jnp.float32)
-    mode = kernels_mod.kernel_mode(force)
+    mode = dispatch_mode(force)
     if batch_by_word is None:
         batch_by_word = mode == "pallas"
     order = None

@@ -27,6 +27,8 @@ the stale proposals exact rather than approximate.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -76,7 +78,8 @@ def _sweep_step(carry, _, wn, order, ns, n_topics):
 
 def _sweep_row(wn, order, ns):
     """Alias sweep of ONE normalized row. wn [K] f32, order [K] int32 (smalls
-    in index order, then larges), ns [] int32 (small count)."""
+    in index order, then larges), ns [] int32 (small count). Returns the
+    per-step (slot, prob, alias) triples, each [K]."""
     K = wn.shape[0]
     has_l = ns < K
     first = order[jnp.minimum(ns, K - 1)]
@@ -84,11 +87,15 @@ def _sweep_row(wn, order, ns):
     curw0 = jnp.where(has_l, wn[first], 0.0)
     carry0 = (jnp.int32(0), jnp.int32(1), cur0, curw0, jnp.int32(-1),
               jnp.float32(0.0))
-    import functools
-
     step = functools.partial(_sweep_step, wn=wn, order=order, ns=ns,
                              n_topics=K)
-    _, (slots, vals, alis) = jax.lax.scan(step, carry0, None, length=K)
+    _, out = jax.lax.scan(step, carry0, None, length=K)
+    return out
+
+
+def _write_row(slots, vals, alis):
+    """Scatter one row's finalized triples into its (prob, alias) tables."""
+    K = slots.shape[0]
     # every live step finalizes exactly one slot; idle tail steps emit
     # slot = -1 → redirected out of bounds and dropped (defaults: prob 1,
     # alias self — the same values a live finalize would have written)
@@ -102,8 +109,13 @@ def _sweep_row(wn, order, ns):
 def build_alias_ref(wn, order, ns):
     """Batched alias construction. wn [R, K] normalized (mean 1) weights,
     order [R, K] small/large partition order, ns [R] small counts — all from
-    ``ops._prepare``. Returns (prob [R, K] f32, alias [R, K] int32)."""
-    return jax.vmap(_sweep_row)(wn, order, ns)
+    ``ops._prepare``. Returns (prob [R, K] f32, alias [R, K] int32).
+
+    The sweeps run side by side across rows; the table writes run row by
+    row, since one [R, K] scatter costs the TPU compiler ~25 s at
+    R·K ≈ 10⁸ and a loop of [K] scatters about 2 s."""
+    steps = jax.vmap(_sweep_row)(wn, order, ns)
+    return jax.lax.map(lambda t: _write_row(*t), steps)
 
 
 # --------------------------------------------------------------- probe ------

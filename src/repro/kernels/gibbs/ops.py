@@ -11,7 +11,6 @@ jitted loops never re-walks the backend registry.
 from __future__ import annotations
 
 from repro import kernels as kernels_mod
-from repro.kernels import on_tpu as _on_tpu  # cached probe (back-compat name)
 from repro.kernels.gibbs.kernel import gibbs_argmax_pallas
 from repro.kernels.gibbs.ref import gibbs_argmax_ref
 

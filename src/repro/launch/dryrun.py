@@ -79,9 +79,7 @@ def run_cell(spec, shape: str, multi_pod: bool, skip_jaxpr: bool = False) -> dic
         rec["live_bytes_per_device"] = int(live)
         rec["fits_16gb_hbm"] = bool(live < 16e9)
 
-        from repro._compat import cost_analysis_dict
-
-        ca = cost_analysis_dict(compiled)
+        ca = compiled.cost_analysis()
         rec["xla_cost"] = {"flops": float(ca.get("flops", 0.0)),
                            "bytes": float(ca.get("bytes accessed", 0.0))}
 

@@ -16,7 +16,8 @@ so the coordinator schedule exists exactly once.
     model, info = tr.export_model()        # dedup + merge → RT-LDA
 
 ``export_model`` is the shared train→serve export: one O(K²V) L1 distance
-pass feeds both the duplicate-fraction diagnostic and the cluster merge,
+pass, blocked on the device, feeds both the duplicate-fraction diagnostic
+and the cluster merge,
 then the merged counts become an :class:`RTLDAModel` (R cache, Eq. 3).
 ``ModelPublisher`` calls the same method on a cadence and writes versioned
 snapshots a serving-side ``SnapshotWatcher`` hot-swaps into a
@@ -225,6 +226,14 @@ class Trainer:
             sampler=cfg.sampler, n_mh=cfg.n_mh, doc_topic_cap=doc_cap,
             model_shards=cfg.n_model_shards)
         elastic = any(isinstance(cb, ElasticLiveness) for cb in self.callbacks)
+        parts = (hierarchy.pod_ring_epoch_parts if cfg.multi_pod
+                 else dist.ring_epoch_parts)(self.mesh, self.ring_cfg)
+        # every epoch call gets its arguments in these placements: the
+        # host-built first-epoch state, later epochs' sharded outputs and a
+        # freshly optimized α would otherwise be three signatures, i.e.
+        # three compiles of one program
+        self._epoch_in = tuple(jax.sharding.NamedSharding(self.mesh, s)
+                               for s in parts[1])
         if cfg.multi_pod:
             self._epoch_fn = hierarchy.make_pod_ring_epoch(self.mesh,
                                                            self.ring_cfg)
@@ -338,7 +347,7 @@ class Trainer:
         import jax
 
         t0 = time.perf_counter()
-        out = self._epoch_fn(*args)
+        out = self._epoch_fn(*jax.device_put(args, self._epoch_in))
         jax.block_until_ready(out)
         dt = time.perf_counter() - t0
         if self._streaming:
@@ -637,9 +646,10 @@ class Trainer:
                      dup_l1: Optional[float] = None):
         """Dedup + merge + RT-LDA build (paper §3.3 → §3.2 handoff).
 
-        One shared ``pairwise_l1`` distance pass feeds the duplicate-fraction
-        diagnostic and the cluster merge; merged counts + merged α become the
-        serving model. Returns ``(RTLDAModel, info)`` with
+        One shared ``l1_scan`` distance pass (blocked, on the device) feeds
+        the duplicate-fraction diagnostic and the cluster merge; merged
+        counts + merged α become the serving model. Returns
+        ``(RTLDAModel, info)`` with
         ``info = {duplicate_fraction, n_topics, n_topics_raw}``.
         """
         import jax.numpy as jnp
@@ -650,11 +660,12 @@ class Trainer:
         merge_l1 = cfg.dedup_merge_l1 if merge_l1 is None else merge_l1
         dup_l1 = cfg.dedup_dup_l1 if dup_l1 is None else dup_l1
         _, psi0 = self.local_model()
-        phi_full = jnp.asarray(self.gather_phi())
-        d_l1 = dedup.pairwise_l1(phi_full, self.beta)
-        frac = dedup.duplicate_fraction(phi_full, self.beta, dup_l1, dist=d_l1)
+        phi_full = self.gather_phi()
+        scan = dedup.l1_scan(phi_full, self.beta, merge_l1)
+        frac = dedup.duplicate_fraction(phi_full, self.beta, dup_l1,
+                                        scan=scan)
         cl, ncl = dedup.cluster_topics(phi_full, self.beta,
-                                       l1_threshold=merge_l1, dist=d_l1)
+                                       l1_threshold=merge_l1, scan=scan)
         phi_m, psi_m, alpha_m = dedup.merge_topics(phi_full, psi0, self.alpha,
                                                    cl, ncl)
         model = rtlda.build_model(jnp.asarray(phi_m), self.beta,

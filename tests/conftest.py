@@ -4,10 +4,6 @@ import sys
 
 import pytest
 
-import _hypothesis_fallback
-
-_hypothesis_fallback.install_if_missing()
-
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SRC = os.path.join(REPO, "src")
 

@@ -4,12 +4,8 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
-# second line of defense behind conftest's _hypothesis_fallback: if the
-# fallback is ever removed, this module skips instead of dying at collection
-hypothesis = pytest.importorskip("hypothesis")
-from hypothesis import given, settings, strategies as st  # noqa: E402
+from hypothesis import given, settings, strategies as st
 
 from repro.checkpoint import io
 from repro.checkpoint.manager import CheckpointManager

@@ -1,6 +1,7 @@
 """Topic de-duplication: asymmetric prior fixed point + L1 clustering."""
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core import dedup
@@ -102,22 +103,23 @@ def test_duplicate_fraction_detects_duplicates():
 
 
 def test_precomputed_distance_matches_and_conserves_counts():
-    """cluster_topics/duplicate_fraction accept one shared pairwise_l1 pass."""
+    """cluster_topics/duplicate_fraction accept one shared l1_scan pass."""
     rng = np.random.default_rng(3)
     phi = rng.integers(0, 30, (40, 9)).astype(np.int32)
     phi[:, 5] = phi[:, 2]
     phi[:, 7] = phi[:, 0]
-    d = dedup.pairwise_l1(phi, 0.01)
+    scan = dedup.l1_scan(phi, 0.01, 1e-6)
 
-    cl_pre, n_pre = dedup.cluster_topics(phi, 0.01, 1e-6, dist=d)
+    cl_pre, n_pre = dedup.cluster_topics(phi, 0.01, 1e-6, scan=scan)
     cl, n = dedup.cluster_topics(phi, 0.01, 1e-6)
     np.testing.assert_array_equal(cl_pre, cl)
     assert n_pre == n and n <= 7
 
-    f_pre = dedup.duplicate_fraction(phi, 0.01, 1e-6, dist=d)
+    f_pre = dedup.duplicate_fraction(phi, 0.01, 1e-6, scan=scan)
     assert f_pre == dedup.duplicate_fraction(phi, 0.01, 1e-6)
-    # the shared matrix is not mutated by duplicate_fraction's diagonal fill
-    assert np.isfinite(np.diagonal(d)).all()
+    # a scan taken at another threshold cannot stand in for the merge's
+    with pytest.raises(ValueError):
+        dedup.cluster_topics(phi, 0.01, 0.5, scan=scan)
 
     psi = phi.sum(axis=0)
     alpha = np.full(phi.shape[1], 0.4, np.float32)
@@ -126,3 +128,120 @@ def test_precomputed_distance_matches_and_conserves_counts():
     assert int(np.asarray(psi_m).sum()) == int(psi.sum())
     np.testing.assert_allclose(float(np.asarray(alpha_m).sum()),
                                float(alpha.sum()), rtol=1e-6)
+
+
+def _numpy_pairwise_l1(phi, beta):
+    """The dense host pass the blocked device scan replaced: the full K×K
+    float64 L1 matrix between normalized topic columns."""
+    pvk = np.asarray(phi, np.float64) + float(beta)
+    pvk = pvk / pvk.sum(axis=0, keepdims=True)
+    return np.abs(pvk[:, :, None] - pvk[:, None, :]).sum(axis=0)
+
+
+def _numpy_clusters(d, thr):
+    K = d.shape[0]
+    parent = list(range(K))
+
+    def find(x):
+        while parent[x] != x:
+            x = parent[x]
+        return x
+
+    for a, b in zip(*np.where((d < thr) & (np.triu(np.ones_like(d), 1) > 0))):
+        ra, rb = find(int(a)), find(int(b))
+        if ra != rb:
+            parent[max(ra, rb)] = min(ra, rb)
+    _, cluster_of = np.unique([find(k) for k in range(K)], return_inverse=True)
+    return cluster_of, int(cluster_of.max()) + 1
+
+
+@pytest.mark.parametrize("K,block,n_dup", [(9, 4, 2), (37, 8, 5), (64, 64, 0),
+                                          (130, 32, 12)])
+def test_blocked_scan_matches_numpy_pass(K, block, n_dup):
+    """Blocked on-device scan == the old dense numpy pass: same clusters and
+    duplicate fraction, at thresholds that cut through the distance spread."""
+    rng = np.random.default_rng(K)
+    V = 50
+    phi = rng.integers(0, 8, (V, K)).astype(np.int32)
+    src = rng.choice(K, n_dup)
+    for j, s in zip(rng.choice(K, n_dup, replace=False), src):
+        phi[:, j] = phi[:, s]          # exact duplicates (distance 0)
+    d = _numpy_pairwise_l1(phi, 0.01)
+    u = np.unique(d[~np.eye(K, dtype=bool)])
+
+    def gap_threshold(q):
+        # midway across a clear gap in the sorted distances, so float32 vs
+        # float64 rounding cannot move any pair across the threshold
+        i = int(q * (len(u) - 1))
+        while u[i + 1] - u[i] < 1e-4:
+            i += 1
+        return float(u[i] + u[i + 1]) / 2
+
+    for thr in (1e-6, gap_threshold(0.02), gap_threshold(0.3)):
+        scan = dedup.l1_scan(phi, 0.01, thr, block=block)
+        cl, ncl = dedup.cluster_topics(phi, 0.01, thr, scan=scan)
+        ref_cl, ref_n = _numpy_clusters(d, thr)
+        np.testing.assert_array_equal(cl, ref_cl)
+        assert ncl == ref_n
+        dd = d.copy()
+        np.fill_diagonal(dd, np.inf)
+        ref_frac = float((dd.min(axis=0) < thr).mean())
+        assert dedup.duplicate_fraction(phi, 0.01, thr, scan=scan) == ref_frac
+        np.testing.assert_allclose(scan.nearest, dd.min(axis=0), rtol=1e-5,
+                                   atol=1e-6)
+
+
+@pytest.mark.parametrize("D,K,T", [(3, 4, 6), (50, 7, 400), (200, 33, 1500)])
+def test_omega_from_pairs_matches_dense_theta(D, K, T):
+    """Ω from the sparse (doc, topic, count) pairs == Ω from a dense Θ."""
+    rng = np.random.default_rng(D)
+    doc_ids = rng.integers(0, D, T).astype(np.int32)
+    z = rng.integers(0, K, T).astype(np.int32)
+    valid = rng.uniform(size=T) < 0.9
+    max_count = 8
+    theta = np.zeros((D, K), np.int64)
+    np.add.at(theta, (doc_ids[valid], z[valid]), 1)
+    ref = np.zeros((K, max_count), np.int64)
+    for k in range(K):
+        np.add.at(ref[k], np.minimum(theta[:, k], max_count - 1), 1)
+    ref[:, 0] = 0
+    omega = dedup.topic_count_histogram(
+        jnp.asarray(doc_ids), jnp.asarray(z), jnp.asarray(valid), D, K,
+        max_count=max_count)
+    np.testing.assert_array_equal(np.asarray(omega), ref)
+
+
+def test_alpha_step_and_export_at_paper_widths():
+    """The α statistics compile at the from_peacock_lda widths with
+    temporaries far below a dense [n_docs, K] Θ, and the α step and the
+    export's device programs trace there on abstract shapes (their TPU
+    compiles, with memory, are in test_tpu_compile.py)."""
+    import jax
+
+    from repro.core import rtlda
+    from repro.training import TrainerConfig
+
+    cfg = TrainerConfig.from_peacock_lda()
+    K, V = cfg.n_topics, cfg.vocab_size
+    n_docs = cfg.n_docs
+    T = int(n_docs * 4.5)
+    sds = jax.ShapeDtypeStruct
+    hist = jax.jit(dedup.topic_count_histogram,
+                   static_argnames=("n_docs", "n_topics", "max_count"))
+    c = hist.lower(sds((T,), jnp.int32), sds((T,), jnp.int32),
+                   sds((T,), jnp.bool_), n_docs=n_docs,
+                   n_topics=K).compile()
+    assert c.memory_analysis().temp_size_in_bytes < 1e9     # Θ: 4.3e11
+    alpha = jax.eval_shape(dedup.optimize_alpha, sds((K,), jnp.float32),
+                           sds((K, 64), jnp.int32), sds((512,), jnp.int32))
+    assert alpha.shape == (K,)
+    block = 512
+    kp = K + (-K) % block
+    near, n_below, packed = jax.eval_shape(
+        dedup._l1_row_block, sds((V, kp), jnp.float32), 0, jnp.float32(0.3),
+        block, K)
+    assert near.shape == (block,) and n_below.shape == ()
+    assert packed.shape == (block, kp // 8)
+    model = jax.eval_shape(rtlda.build_model, sds((V, K), jnp.int32),
+                           jnp.float32(0.01), sds((K,), jnp.float32))
+    assert model.pvk.shape == (V, K) and model.r_topic.shape == (V,)

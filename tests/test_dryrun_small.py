@@ -8,7 +8,6 @@ CODE = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, json
-from repro._compat import cost_analysis_dict
 from repro.configs import get_arch
 from repro.launch.mesh import make_test_mesh
 
@@ -29,7 +28,7 @@ for arch, shape, mp in cells:
     spec = get_arch(arch)
     cell = spec.cell(shape, mesh3 if mp else mesh, mp)
     compiled = cell.lower().compile()
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     results[f"{arch}/{shape}/{'mp' if mp else 'sp'}"] = float(ca.get("flops", 0))
 print("DRYRUN_SMALL_OK", json.dumps(list(results)))
 """

@@ -32,6 +32,19 @@ def test_alias_build_kernel_matches_ref(R, K):
     np.testing.assert_array_equal(np.asarray(ar), np.asarray(ak))
 
 
+@pytest.mark.parametrize("R,K", [(1, 1), (3, 40), (6, 1000)])
+def test_prepare_partition_is_the_stable_argsort(R, K):
+    """The prefix-sum partition in ``_prepare`` == a stable argsort of the
+    small/large flags (smalls in index order, then larges)."""
+    w = jnp.asarray(RNG.gamma(0.3, 1.0, (R, K)).astype(np.float32))
+    w = w.at[0, :K // 2].set(0.0)          # zero-weight slots are smalls
+    wn, order, ns = alias_ops._prepare(w)
+    large = np.asarray(wn) >= 1.0
+    np.testing.assert_array_equal(
+        np.asarray(order), np.argsort(large, axis=-1, kind="stable"))
+    np.testing.assert_array_equal(np.asarray(ns), (~large).sum(axis=-1))
+
+
 @pytest.mark.parametrize("shape", [(4, 64), (2, 3, 32)])
 def test_alias_invariant_reconstructs_distribution(shape):
     """prob/alias must reconstruct the normalized input exactly:

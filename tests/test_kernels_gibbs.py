@@ -90,6 +90,21 @@ def test_prng_uniform_range(seed, a, b):
     assert 0.0 < u < 1.0
 
 
+def test_prng_unit_interval_is_open_at_both_ends():
+    """The extreme 24-bit draws stay inside (0, 1), so Gumbel noise is
+    finite: the top value once rounded to 1.0 and gave +inf, a topic that
+    beat the posterior about once per 170 tokens at K = 10⁵. Every other
+    value is the float32 (bits + 0.5) / 2**24 it always was."""
+    low = np.array([0, 1, 2**23, 2**24 - 2], np.float32)
+    bits = jnp.array([0, 1, 2**23, 2**24 - 2, 2**24 - 1], jnp.uint32)
+    u = np.asarray(prng.unit_interval(bits))
+    assert (u > 0).all() and (u < 1).all()
+    np.testing.assert_array_equal(
+        u[:4], (low + np.float32(0.5)) * np.float32(2.0 ** -24))
+    g = -np.log(-np.log(u.astype(np.float64)))
+    assert np.isfinite(g).all()
+
+
 def test_prng_avalanche():
     """Adjacent counters must produce decorrelated bits (murmur3 finalizer)."""
     n = 4096

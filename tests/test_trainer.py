@@ -308,6 +308,35 @@ def test_segment_cadence_covers_every_boundary(tmp_path):
     assert steps == [1, 2, 3, 4], steps
 
 
+@pytest.mark.parametrize("sampler", ["dense", "alias"])
+def test_session_compiles_its_epoch_once(sampler):
+    """Host-built first-epoch state, later epochs' sharded outputs and an
+    optimized α reach the epoch in one placement: one compile per session
+    (each extra one cost ~50 s at K = 10⁵ on a TPU v5e)."""
+    import jax
+
+    from repro.training import AlphaOptimizer, Trainer, TrainerConfig
+
+    compiles = []
+
+    def on_duration(event, duration, **kw):
+        if (event == "/jax/core/compile/backend_compile_duration"
+                and kw.get("fun_name") == "jit(epoch)"):
+            compiles.append(duration)
+
+    cfg = TrainerConfig(n_docs=80, vocab_size=50, n_topics=6, true_topics=3,
+                        n_epochs=3, agg_every=1, alpha_opt_from=1,
+                        sampler=sampler, seed=len(sampler))
+    tr = Trainer(cfg, callbacks=[AlphaOptimizer()])
+    tr.log = lambda m: None
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        tr.fit()
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+    assert len(compiles) == 1, compiles
+
+
 def test_checkpoint_cadences_refuse_sessions_they_cannot_fire_in(tmp_path):
     """every_boundaries on a never-aggregating session (and every_segments
     on a 1-segment one) would silently write zero checkpoints — data loss
