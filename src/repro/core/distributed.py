@@ -185,16 +185,18 @@ def _sample_subblock_mh(phi, psi, pairs, w, d, z, uid, alpha, beta, seed,
         valid = w >= 0
         w_s = jnp.where(valid, w, 0)
         d_s = jnp.where(valid, d, 0)
-        z_new = alias_ops.mh_resample(
-            phi, psi, tp, ct, tables.wq, tables.wp, tables.wa, alpha,
-            tables.ap, tables.aa, w_s, d_s, z, uid.astype(jnp.uint32),
-            jnp.asarray(seed, jnp.uint32), beta, cfg.vocab_size, cfg.n_mh,
-            force="pallas" if cfg.use_kernel else None)
+        with jax.named_scope("mh_resample"):
+            z_new = alias_ops.mh_resample(
+                phi, psi, tp, ct, tables.wq, tables.wp, tables.wa, alpha,
+                tables.ap, tables.aa, w_s, d_s, z, uid.astype(jnp.uint32),
+                jnp.asarray(seed, jnp.uint32), beta, cfg.vocab_size,
+                cfg.n_mh, force="pallas" if cfg.use_kernel else None)
         z_new = jnp.where(valid, z_new, z)
         delta = valid.astype(jnp.int32)
         phi = phi.at[w_s, z].add(-delta).at[w_s, z_new].add(delta)
         psi = psi.at[z].add(-delta).at[z_new].add(delta)
-        tp, ct = sparse.apply_deltas(tp, ct, d_s, z, z_new, valid)
+        with jax.named_scope("apply_deltas"):
+            tp, ct = sparse.apply_deltas(tp, ct, d_s, z, z_new, valid)
         return (phi, psi, tp, ct), z_new
 
     (phi, psi, tp, ct), z_new = jax.lax.scan(
@@ -285,7 +287,8 @@ def build_epoch_body(mesh, cfg: RingConfig, pod_axis=None):
             buf = jax.lax.dynamic_update_slice(buf, a[None], (mj, 0, 0))
             cur = a
             for h in range(1, Pm):
-                cur = jax.lax.ppermute(cur, RING_AXES[1], perm_m)
+                with jax.named_scope("ring_exchange"):
+                    cur = jax.lax.ppermute(cur, RING_AXES[1], perm_m)
                 # hop h delivers the bucket of model rank (mj − h) % P
                 buf = jax.lax.dynamic_update_slice(
                     buf, cur[None], ((mj - h) % Pm, 0, 0))
@@ -300,9 +303,10 @@ def build_epoch_body(mesh, cfg: RingConfig, pod_axis=None):
             # ship the immutable stack arrays for the NEXT round first — XLA
             # overlaps the collective-permute with this round's sampling
             # (pipeline, §3.1.2); z ships after sampling updates it.
-            nxt = tuple(
-                jax.lax.ppermute(a, rot_axes, perm) for a in (wl, dl, uid)
-            )
+            with jax.named_scope("ring_exchange"):
+                nxt = tuple(
+                    jax.lax.ppermute(a, rot_axes, perm) for a in (wl, dl, uid)
+                )
 
             # Θ for the visiting shard's documents, rebuilt from the stack's z
             if Pm > 1:
@@ -336,8 +340,9 @@ def build_epoch_body(mesh, cfg: RingConfig, pod_axis=None):
                 from repro.core import sparse as sparse_mod
 
                 cap_p = cfg.doc_topic_cap or cfg.n_topics
-                pairs = sparse_mod.pairs_from_assignments(
-                    flat_d, flat_z, flat_valid, cfg.docs_per_shard, cap_p)
+                with jax.named_scope("doc_pairs"):
+                    pairs = sparse_mod.pairs_from_assignments(
+                        flat_d, flat_z, flat_valid, cfg.docs_per_shard, cap_p)
                 phi_l, psi_l, _, z_new = _sample_subblock_mh(
                     phi_l, psi_l, pairs, w_sub, d_sub, z_sub, u_sub,
                     alpha, beta, seed, cfg_l, tabs)
@@ -369,13 +374,16 @@ def build_epoch_body(mesh, cfg: RingConfig, pod_axis=None):
                 # only its bucket's deltas; summing them restores the
                 # replicated round-end Ψ, so the next round's snapshot — and
                 # every z it samples — matches the P = 1 path bitwise
-                psi_l = psi_r0 + jax.lax.psum(psi_l - psi_r0, RING_AXES[1])
+                with jax.named_scope("ring_exchange"):
+                    psi_l = psi_r0 + jax.lax.psum(psi_l - psi_r0,
+                                                  RING_AXES[1])
             # write updated z back into the (already-shipped view of the) stack:
             # the z we forward must include this round's update, so we update
             # BEFORE shipping in program order — instead we re-ship z only.
             z_upd = jax.lax.dynamic_update_slice_in_dim(z, z_new[None], me,
                                                         axis=0)
-            z_next = jax.lax.ppermute(z_upd, rot_axes, perm)
+            with jax.named_scope("ring_exchange"):
+                z_next = jax.lax.ppermute(z_upd, rot_axes, perm)
             stack = (nxt[0], nxt[1], nxt[2], z_next)
             return (phi_l, psi_l, stack), None
 
@@ -385,7 +393,8 @@ def build_epoch_body(mesh, cfg: RingConfig, pod_axis=None):
         # relaxed per-segment Ψ synchronization (Fig. 4); with model sharding
         # the per-round resync already made model ranks replicas, so the
         # epoch-end psum runs over the data ring only
-        psi_out = psi0 + jax.lax.psum(psi_l - psi0, rot_axes)
+        with jax.named_scope("ring_exchange"):
+            psi_out = psi0 + jax.lax.psum(psi_l - psi0, rot_axes)
         unsq = lambda a: a.reshape((1,) * lead + a.shape)
         return (unsq(phi_l), psi_out.reshape((1,) * plead + psi_out.shape),
                 *(unsq(s) for s in stack))

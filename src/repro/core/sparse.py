@@ -24,7 +24,9 @@ Three vectorized primitives (no per-token host loops, all jit-safe):
 
 Table builders (:func:`make_word_tables`, :func:`make_alpha_table`) produce
 the stale proposal tables the MH probe corrects against; the Trainer rebuilds
-them at aggregation boundaries from merged Φ.
+them at aggregation boundaries from merged Φ. Each table's Walker build is a
+program of its own name (``build_alias_word``, ``build_alias_alpha``), so a
+device trace tells the two apart.
 """
 from __future__ import annotations
 
@@ -211,6 +213,21 @@ def apply_deltas(topic, count, d, z_old, z_new, valid):
 # --------------------------------------------------------- table builders ---
 
 
+def _table_build(name: str):
+    """``alias_ops.alias_tables`` as a program named ``name``: the traced
+    function of ``alias_ops.build_alias``, so the same HLO, under a name of
+    its own."""
+    def build(weights, *, force: str | None = None):
+        return alias_ops.alias_tables(weights, force=force)
+
+    build.__name__ = build.__qualname__ = name
+    return jax.jit(build, static_argnames=("force",))
+
+
+build_alias_word = _table_build("build_alias_word")
+build_alias_alpha = _table_build("build_alias_alpha")
+
+
 def make_word_tables(phi, psi, beta, vocab_size: int, *,
                      force: str | None = None) -> Tuple[jax.Array, ...]:
     """Stale word-proposal tables from a Φ snapshot.
@@ -225,15 +242,15 @@ def make_word_tables(phi, psi, beta, vocab_size: int, *,
         psi_b = jnp.expand_dims(psi_b, -2)
     wq = (phi.astype(jnp.float32) + beta) / (
         psi_b + jnp.float32(vocab_size) * beta)
-    wp, wa = alias_ops.build_alias(wq, force=force)
+    wp, wa = build_alias_word(wq, force=force)
     return wq, wp, wa
 
 
 def make_alpha_table(alpha, *, force: str | None = None):
     """α alias table (ap [K] f32, aa [K] int32) — rebuilt whenever the Minka
     fixed point moves α (cheap: one K-row build)."""
-    ap, aa = alias_ops.build_alias(alpha[None, :].astype(jnp.float32),
-                                   force=force)
+    ap, aa = build_alias_alpha(alpha[None, :].astype(jnp.float32),
+                               force=force)
     return ap[0], aa[0]
 
 

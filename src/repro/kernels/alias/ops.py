@@ -6,7 +6,7 @@ Same contract as ``kernels/gibbs/ops.py``: ``force`` in {None, "pallas",
 run the jnp implementations (``ref.py``), compiled by XLA. On TPU that is
 :data:`TPU_MODE`, for the reason given there.
 
-``build_alias`` normalizes + partitions ONCE here (``_prepare``) and hands
+``alias_tables`` normalizes + partitions ONCE here (``_prepare``) and hands
 identical inputs to whichever sweep implementation runs — ref vs kernel
 agreement is bitwise because only the K-step sweep differs in execution
 strategy, never in arithmetic. ``mh_resample`` likewise mixes the sampler
@@ -70,9 +70,9 @@ def _prepare(weights):
     return wn, order, ns
 
 
-@functools.partial(jax.jit, static_argnames=("force",))
-def build_alias(weights, *, force: str | None = None):
-    """Batched Walker alias tables over the trailing axis.
+def alias_tables(weights, *, force: str | None = None):
+    """Batched Walker alias tables over the trailing axis (traceable; the
+    program is :func:`build_alias`).
 
     weights [..., K] nonneg f32 → (prob [..., K] f32, alias [..., K] int32)
     with the table identity  q(k) = (prob_k + Σ_j (1−prob_j)·1[alias_j = k])/K
@@ -89,6 +89,12 @@ def build_alias(weights, *, force: str | None = None):
     else:
         prob, alias = build_alias_ref(wn, order, ns)
     return prob.reshape(*lead, K), alias.reshape(*lead, K)
+
+
+@functools.partial(jax.jit, static_argnames=("force",))
+def build_alias(weights, *, force: str | None = None):
+    """:func:`alias_tables` as a program of its own."""
+    return alias_tables(weights, force=force)
 
 
 def mh_resample(

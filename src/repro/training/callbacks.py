@@ -26,8 +26,9 @@ the epochs after ``meta["step"]`` — no trainer code knows about it.
 from __future__ import annotations
 
 import json
-import time
 from typing import Optional
+
+from repro.training import spans
 
 
 class TrainerCallback:
@@ -217,10 +218,12 @@ class AlphaOptimizer(TrainerCallback):
         start = cfg.alpha_opt_from if self.from_epoch is None else self.from_epoch
         if epoch < start:
             return
-        omega, hist = trainer.alpha_statistics()
-        n_iters = cfg.alpha_opt_iters if self.n_iters is None else self.n_iters
-        trainer.alpha = dedup.optimize_alpha(trainer.alpha, omega, hist,
-                                             n_iters=n_iters)
+        with spans.span("peacock.train.alpha_step"):
+            omega, hist = trainer.alpha_statistics()
+            n_iters = (cfg.alpha_opt_iters if self.n_iters is None
+                       else self.n_iters)
+            trainer.alpha = dedup.optimize_alpha(trainer.alpha, omega, hist,
+                                                 n_iters=n_iters)
 
 
 class KillSwitch(TrainerCallback):
@@ -308,15 +311,14 @@ class Metrics(TrainerCallback):
         self._t0 = None
 
     def on_train_start(self, trainer) -> None:
-        self._t0 = time.time()
+        self._t0 = spans.now()
 
     def on_epoch_end(self, trainer, epoch: int) -> None:
         if (epoch + 1) % self.log_every != 0:
             return
         ll = trainer.log_likelihood()
         trainer.metrics["ll"].append(ll)
-        trainer.metrics["ll_epoch"].append(epoch + 1)
-        elapsed = time.time() - (self._t0 or time.time())
+        elapsed = spans.now() - (self._t0 or spans.now())
         msg = (f"epoch {epoch + 1:3d}/{trainer.config.n_epochs}  "
                f"LL {ll:,.0f}  ({elapsed:.1f}s)")
         (self.printer or trainer.log)(msg)

@@ -19,10 +19,10 @@ each new version into a live ``TopicEngine`` with zero dropped requests.
 """
 from __future__ import annotations
 
-import time
 from typing import Optional
 
 from repro.checkpoint import snapshots
+from repro.training import spans
 from repro.training.callbacks import TrainerCallback
 
 
@@ -108,30 +108,31 @@ class ModelPublisher(TrainerCallback):
         """Export + write one snapshot now; returns the new version."""
         import numpy as np
 
-        t0 = time.perf_counter()
-        model, info = trainer.export_model(merge_l1=self.merge_l1,
-                                           dup_l1=self.dup_l1)
-        latest = snapshots.latest_version(self.snapshot_dir)
-        version = 0 if latest is None else latest + 1
-        meta = {"epoch": epoch + 1, **info}
-        pvk = np.asarray(model.pvk)
-        as_delta = (self.delta and self._base_pvk is not None
-                    and self._since_full < self.full_every - 1
-                    and pvk.shape == self._base_pvk.shape)
-        if as_delta:
-            path = snapshots.save_delta_snapshot(
-                self.snapshot_dir, version, model,
-                self._base_version, self._base_pvk, meta)
-            self._since_full += 1
-        else:
-            path = snapshots.save_snapshot(
-                self.snapshot_dir, version, model, meta)
-            self._since_full = 0
-        # next publish diffs against THIS payload (delta-over-delta chains
-        # are fine: the loader walks bases, full_every bounds the depth)
-        self._base_pvk, self._base_version = pvk.copy(), version
-        snapshots.rotate_snapshots(self.snapshot_dir, self.keep)
-        latency = time.perf_counter() - t0
+        with spans.span("peacock.publish") as sp:
+            model, info = trainer.export_model(merge_l1=self.merge_l1,
+                                               dup_l1=self.dup_l1)
+            latest = snapshots.latest_version(self.snapshot_dir)
+            version = 0 if latest is None else latest + 1
+            meta = {"epoch": epoch + 1, **info}
+            pvk = np.asarray(model.pvk)
+            as_delta = (self.delta and self._base_pvk is not None
+                        and self._since_full < self.full_every - 1
+                        and pvk.shape == self._base_pvk.shape)
+            if as_delta:
+                path = snapshots.save_delta_snapshot(
+                    self.snapshot_dir, version, model,
+                    self._base_version, self._base_pvk, meta)
+                self._since_full += 1
+            else:
+                path = snapshots.save_snapshot(
+                    self.snapshot_dir, version, model, meta)
+                self._since_full = 0
+            # next publish diffs against THIS payload (delta-over-delta
+            # chains are fine: the loader walks bases, full_every bounds
+            # the depth)
+            self._base_pvk, self._base_version = pvk.copy(), version
+            snapshots.rotate_snapshots(self.snapshot_dir, self.keep)
+        latency = sp.duration
         trainer.metrics["publish_s"].append(latency)
         self.last_version, self.last_path = version, path
         self._last_publish_epoch = epoch + 1

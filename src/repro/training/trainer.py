@@ -27,11 +27,11 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-import time
 from typing import Any, Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.training import spans
 from repro.training.callbacks import (AlphaOptimizer, ElasticLiveness,
                                       TrainerCallback)
 from repro.training.config import TrainerConfig
@@ -93,6 +93,9 @@ class Trainer:
         self._omega_from = None      # first epoch that folds Ω incrementally
         self._omega_parts = {}       # segment id → this epoch's Ω part
         self._built = False
+        # the process's span totals so far: bench_record() reports only
+        # what this session adds to them
+        self._spans_from = spans.recorder().totals()
 
     # ------------------------------------------------------------ build ----
 
@@ -101,8 +104,9 @@ class Trainer:
 
     def notify(self, event: str, *args) -> None:
         """Fire one event on every callback, in list order."""
-        for cb in self.callbacks:
-            getattr(cb, event)(self, *args)
+        with spans.span("peacock.train.callbacks", event=event):
+            for cb in self.callbacks:
+                getattr(cb, event)(self, *args)
 
     def _build_source(self):
         """Resolve the session's CorpusSource (explicit > corpus_dir >
@@ -163,13 +167,21 @@ class Trainer:
         Idempotent; ``fit()`` calls it automatically."""
         if self._built:
             return self
-        import jax
-        import jax.numpy as jnp
+        spans.at_epoch(self.epoch, self.segment)
+        with spans.span("peacock.train.setup"):
+            with spans.span("peacock.train.setup.source"):
+                self._setup_source()
+            with spans.span("peacock.train.setup.state"):
+                self._setup_state()
+            with spans.span("peacock.train.setup.programs"):
+                self._setup_programs()
+        self._built = True
+        return self
 
-        from repro.core import distributed as dist, hierarchy
-
+    def _setup_source(self) -> None:
+        """The session's source and its first segment's (or every pod's)
+        shards: the corpus sharding."""
         cfg = self.config
-        K, M = cfg.n_topics, cfg.ring_size
         src = self._build_source()
         # streaming = any session whose stacks are not resident device state:
         # more than one segment, or an out-of-core (corpus-less) source
@@ -178,36 +190,53 @@ class Trainer:
             raise ValueError("segment streaming is single-configuration "
                              "(got a multi-pod session with a streaming "
                              "source)")
-
         if cfg.multi_pod:
             from repro.data import corpus as corpus_mod
 
+            self._scs = corpus_mod.shard_corpus_pods(
+                self.corpus, cfg.n_pods, cfg.ring_size, cfg.ring_size,
+                cfg.n_topics, seed=cfg.shard_seed,
+                n_model_shards=cfg.n_model_shards)
+            self.sc0 = self._scs[0]
+        else:
+            self.sc0 = src.segment(0)
+
+    def _setup_state(self) -> None:
+        """The mesh and the initial device state."""
+        import jax
+
+        from repro.core import distributed as dist, hierarchy
+
+        cfg = self.config
+        if cfg.multi_pod:
             self.mesh = jax.make_mesh(
                 (cfg.n_pods, cfg.data_shards, cfg.model_shards),
                 ("pod", "data", "model"),
                 axis_types=(jax.sharding.AxisType.Auto,) * 3)
-            self._scs = corpus_mod.shard_corpus_pods(
-                self.corpus, cfg.n_pods, M, M, K, seed=cfg.shard_seed,
-                n_model_shards=cfg.n_model_shards)
-            self.sc0 = self._scs[0]
-            self.state = hierarchy.init_pod_state(self._scs, K)
-        elif self._streaming:
-            self.mesh = jax.make_mesh(
-                (cfg.data_shards, cfg.model_shards), ("data", "model"),
-                axis_types=(jax.sharding.AxisType.Auto,) * 2)
-            self.sc0 = src.segment(0)
+            self.state = hierarchy.init_pod_state(self._scs, cfg.n_topics)
+            return
+        self.mesh = jax.make_mesh(
+            (cfg.data_shards, cfg.model_shards), ("data", "model"),
+            axis_types=(jax.sharding.AxisType.Auto,) * 2)
+        if self._streaming:
             # (phi, psi) + the global z store materialize lazily in fit():
             # a resume restores all three from the checkpoint, and the
             # init pass over every segment would be thrown away
             self.state = None
             self._z = None
         else:
-            self.mesh = jax.make_mesh(
-                (cfg.data_shards, cfg.model_shards), ("data", "model"),
-                axis_types=(jax.sharding.AxisType.Auto,) * 2)
-            self.sc0 = src.segment(0)
-            self.state = dist.device_arrays(self.sc0, K)
+            self.state = dist.device_arrays(self.sc0, cfg.n_topics)
 
+    def _setup_programs(self) -> None:
+        """Ring configuration, epoch and aggregate programs, α and β."""
+        import jax
+        import jax.numpy as jnp
+
+        from repro.core import distributed as dist, hierarchy
+
+        cfg = self.config
+        src = self.source
+        K, M = cfg.n_topics, cfg.ring_size
         if cfg.kernel_mode is not None:
             from repro import kernels as kernels_mod
 
@@ -264,8 +293,6 @@ class Trainer:
                       for cb in self.callbacks
                       if isinstance(cb, AlphaOptimizer)]
             self._omega_from = min(starts) if starts else None
-        self._built = True
-        return self
 
     def _materialize_stream_state(self) -> None:
         """ONE pass over the segments building the initial (phi, psi) and
@@ -301,6 +328,7 @@ class Trainer:
         cfg = self.config
         self.notify("on_train_start")
         start_epoch = self.epoch
+        spans.at_epoch(self.epoch, self.segment)    # a restore may move both
         if start_epoch >= cfg.n_epochs:
             self.log(f"[train] nothing to do: resumed at epoch {start_epoch} "
                      f"of {cfg.n_epochs}")
@@ -346,25 +374,28 @@ class Trainer:
     def _timed_epoch(self, *args):
         import jax
 
-        t0 = time.perf_counter()
-        out = self._epoch_fn(*jax.device_put(args, self._epoch_in))
-        jax.block_until_ready(out)
-        dt = time.perf_counter() - t0
+        with spans.span("peacock.train.ring_epoch") as sp:
+            with spans.span("peacock.train.ring_epoch.put"):
+                args = jax.device_put(args, self._epoch_in)
+            with spans.span("peacock.train.ring_epoch.dispatch"):
+                out = self._epoch_fn(*args)
+            with spans.span("peacock.train.ring_epoch.wait"):
+                jax.block_until_ready(out)
         if self._streaming:
             # per-segment wall time; _hook_epoch_end folds the epoch total
-            self.metrics["segment_s"].append(dt)
-            self._ep_time += dt
+            self.metrics["segment_s"].append(sp.duration)
+            self._ep_time += sp.duration
         else:
-            self.metrics["epoch_s"].append(dt)
+            self.metrics["epoch_s"].append(sp.duration)
         return out
 
     def _timed_agg(self, *args, **kwargs):
         import jax
 
-        t0 = time.perf_counter()
-        out = self._agg_fn(*args, **kwargs)
-        jax.block_until_ready(out)
-        self.metrics["agg_s"].append(time.perf_counter() - t0)
+        with spans.span("peacock.train.aggregate") as sp:
+            out = self._agg_fn(*args, **kwargs)
+            jax.block_until_ready(out)
+        self.metrics["agg_s"].append(sp.duration)
         return out
 
     def _hook_aggregate(self, ep: int, state) -> None:
@@ -390,6 +421,7 @@ class Trainer:
         if self._omega_from is not None and ep >= self._omega_from:
             self._fold_segment_omega(seg)
         self.notify("on_segment_end", ep, seg.pos + 1)
+        spans.at_epoch(ep, self.segment)
 
     def _segment_omega(self, dl, z, valid):
         """Ω_kn histogram of one segment's (doc_local, z, valid) host views —
@@ -422,6 +454,7 @@ class Trainer:
             self._ep_time = 0.0
         self.notify("on_epoch_end", ep)
         self._omega_parts.clear()     # next epoch folds fresh parts
+        spans.at_epoch(self.epoch)
         return self.alpha       # callbacks may have replaced it
 
     # --------------------------------------------- state views / helpers ---
@@ -438,11 +471,13 @@ class Trainer:
 
         phi, psi = self.state[0], self.state[1]
         if word or self._tables is None:
-            wq, wp, wa = sparse.make_word_tables(
-                phi, psi, self.beta, self.ring_cfg.vocab_size)
+            with spans.span("peacock.train.tables.word"):
+                wq, wp, wa = sparse.make_word_tables(
+                    phi, psi, self.beta, self.ring_cfg.vocab_size)
         else:
             wq, wp, wa = self._tables.wq, self._tables.wp, self._tables.wa
-        ap, aa = sparse.make_alpha_table(self.alpha)
+        with spans.span("peacock.train.tables.alpha"):
+            ap, aa = sparse.make_alpha_table(self.alpha)
         self._tables = sparse.AliasTables(wq, wp, wa, ap, aa)
         self._tables_alpha = self.alpha
 
@@ -677,7 +712,8 @@ class Trainer:
     # ------------------------------------------------------------- bench ---
 
     def bench_record(self) -> dict:
-        """Machine-readable training bench record (BENCH_train.json)."""
+        """Machine-readable training bench record (BENCH_train.json), with
+        the totals of the spans this session recorded."""
         cfg = self.config
         ep_s = self.metrics.get("epoch_s", [])
         seg_s = self.metrics.get("segment_s", [])
@@ -711,4 +747,5 @@ class Trainer:
             "publish_s_mean": mean(pub_s),
             "n_publishes": len(pub_s),
             "ll_final": ll[-1] if ll else None,
+            "spans": spans.recorder().totals(since=self._spans_from),
         }

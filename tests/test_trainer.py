@@ -148,6 +148,12 @@ assert (phi[0] == phi[1]).all(), "pods disagree after aggregation"
 assert live.last_n_live == 2, live.last_n_live
 assert len(res.metrics["agg_s"]) == 2          # two boundaries timed
 assert pub.last_version == 1                   # one publish per boundary
+from repro.training import spans
+rec = spans.recorder()
+assert res.metrics["agg_s"] == [
+    s.duration for s in rec.recent("peacock.train.aggregate")]
+assert res.metrics["publish_s"] == [
+    s.duration for s in rec.recent("peacock.publish")]
 print("MULTIPOD_TRAINER_OK")
 """
 
