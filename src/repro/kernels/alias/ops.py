@@ -6,12 +6,15 @@ Same contract as ``kernels/gibbs/ops.py``: ``force`` in {None, "pallas",
 run the jnp implementations (``ref.py``), compiled by XLA. On TPU that is
 :data:`TPU_MODE`, for the reason given there.
 
-``alias_tables`` normalizes + partitions ONCE here (``_prepare``) and hands
-identical inputs to whichever sweep implementation runs — ref vs kernel
-agreement is bitwise because only the K-step sweep differs in execution
-strategy, never in arithmetic. ``mh_resample`` likewise mixes the sampler
-seed with a sampler-family salt here (the MH uniform stream must not collide
-with the dense path's Gumbel stream at equal (seed, uid) counters).
+``alias_tables`` normalizes + partitions ONCE here (``_prepare``); both sweep
+implementations take their inputs from it: the kernel reads the weights by
+slot (wn, order, ns), the ref sweep reads them in stream order
+(order, wn_ord, ns) so that it can take them from windows of the stream. Ref
+vs kernel agreement is bitwise because only the K-step sweep differs in
+execution strategy, never in arithmetic. ``mh_resample``
+likewise mixes the sampler seed with a sampler-family salt here (the MH
+uniform stream must not collide with the dense path's Gumbel stream at equal
+(seed, uid) counters).
 """
 from __future__ import annotations
 
@@ -47,9 +50,12 @@ def dispatch_mode(force: str | None = None) -> str:
 def _prepare(weights):
     """Mean-1 normalization + stable small/large partition of [R, K] rows.
 
-    Returns (wn, order, ns): ``order`` lists small slots (w < 1) in index
-    order, then large slots; ``ns`` is the per-row small count. Shared
-    verbatim by the ref and Pallas sweeps.
+    Returns (wn, order, wn_ord, ns): ``order`` lists small slots (w < 1) in
+    index order, then large slots; ``wn_ord`` = ``wn[order]``, the weights in
+    that stream order; ``ns`` is the per-row small count. The Pallas sweep
+    reads (wn, order, ns); the ref sweep reads (order, wn_ord, ns), so that it
+    can take each step's operands from C-wide windows of the stream instead
+    of gathering from the [R, K] rows on every step (``ref.py``).
     """
     K = weights.shape[-1]
     total = jnp.maximum(jnp.sum(weights, axis=-1, keepdims=True),
@@ -65,9 +71,12 @@ def _prepare(weights):
                                              dtype=jnp.int32)) - 1
     slots = jnp.arange(K, dtype=jnp.int32)
     # row by row: one [R, K] scatter costs the TPU compiler ~25 s at
-    # R·K ≈ 10⁸, a loop of [K] scatters about 2 s
-    order = jax.lax.map(lambda p: jnp.zeros_like(p).at[p].set(slots), pos)
-    return wn, order, ns
+    # R·K ≈ 10⁸, a loop of [K] scatters about 2 s. wn_ord rides the same
+    # scatter indices rather than a gather pass of its own over [R, K].
+    order, wn_ord = jax.lax.map(
+        lambda a: (jnp.zeros_like(a[0]).at[a[0]].set(slots),
+                   jnp.zeros_like(a[1]).at[a[0]].set(a[1])), (pos, wn))
+    return wn, order, wn_ord, ns
 
 
 def alias_tables(weights, *, force: str | None = None):
@@ -80,14 +89,15 @@ def alias_tables(weights, *, force: str | None = None):
     """
     lead = weights.shape[:-1]
     K = weights.shape[-1]
-    wn, order, ns = _prepare(weights.reshape(-1, K).astype(jnp.float32))
+    wn, order, wn_ord, ns = _prepare(
+        weights.reshape(-1, K).astype(jnp.float32))
     mode = dispatch_mode(force)
     if mode == "pallas":
         prob, alias = alias_build_pallas(wn, order, ns)
     elif mode == "interpret":
         prob, alias = alias_build_pallas(wn, order, ns, interpret=True)
     else:
-        prob, alias = build_alias_ref(wn, order, ns)
+        prob, alias = build_alias_ref(order, wn_ord, ns)
     return prob.reshape(*lead, K), alias.reshape(*lead, K)
 
 

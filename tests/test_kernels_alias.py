@@ -32,17 +32,110 @@ def test_alias_build_kernel_matches_ref(R, K):
     np.testing.assert_array_equal(np.asarray(ar), np.asarray(ak))
 
 
+def _walker_oracle(wn, order, ns):
+    """The Walker sweep as a plain sequential loop (NumPy, float32, rows side
+    by side), gathering its four operands from the whole rows on every step:
+    the schedule ``ref._sweep_step`` follows, written out independently."""
+    R, K = wn.shape
+    rows = np.arange(R)
+    one, zero = np.float32(1.0), np.float32(0.0)
+    prob = np.ones((R, K), np.float32)
+    alias = np.tile(np.arange(K, dtype=np.int32), (R, 1))
+    has_l = ns < K
+    first = order[rows, np.minimum(ns, K - 1)]
+    cur = np.where(has_l, first, -1)
+    curw = np.where(has_l, wn[rows, first], zero).astype(np.float32)
+    i, j = np.zeros(R, np.int64), np.ones(R, np.int64)
+    pend, pendw = np.full(R, -1), np.zeros(R, np.float32)
+    for _ in range(K):
+        has_pend, has_small = pend >= 0, i < ns
+        oi = order[rows, np.minimum(i, K - 1)]
+        s_slot = np.where(has_pend, pend, np.where(has_small, oi, -1))
+        sw = np.where(has_pend, pendw,
+                      np.where(has_small, wn[rows, oi], zero))
+        i = np.where(~has_pend & has_small, i + 1, i)
+        use_small = (s_slot >= 0) & (cur >= 0)
+        slot = np.where(s_slot >= 0, s_slot, cur)
+        live = slot >= 0
+        prob[rows[live], slot[live]] = np.where(
+            use_small, np.clip(sw, zero, one), one)[live]
+        alias[rows[live], slot[live]] = np.where(use_small, cur, slot)[live]
+        curw2 = np.where(use_small, curw - (one - sw), curw)
+        demote = use_small & (curw2 < one)
+        advance = demote | ((s_slot < 0) & (cur >= 0))
+        pend = np.where(demote, cur, -1)
+        pendw = np.where(demote, curw2, zero)
+        nl = ns + j
+        onl = order[rows, np.minimum(nl, K - 1)]
+        cur2 = np.where(advance, np.where(nl < K, onl, -1), cur)
+        curw = np.where(advance, np.where(nl < K, wn[rows, onl], zero),
+                        curw2).astype(np.float32)
+        cur = cur2
+        j = np.where(advance, j + 1, j)
+    return prob, alias
+
+
+def _rows(kind, R, K, rng):
+    """[R, K] nonnegative weights of one family."""
+    if kind == "gamma":
+        return rng.gamma(0.3, 1.0, (R, K))
+    if kind == "onehot":                   # one large absorbs every small
+        return np.eye(K)[rng.integers(0, K, R)]
+    if kind == "cascade":       # larges just above the mean, demoted one
+        w = rng.choice([0.2, 1.05], (R, K))          # after another
+        w[:, rng.integers(0, K)] = 3.0
+        return w
+    if kind == "equal":                    # every wn exactly 1
+        return np.ones((R, K))
+    if kind == "zipf":
+        return rng.permuted(np.tile(1.0 / np.arange(1, K + 1), (R, 1)),
+                            axis=1)
+    if kind == "zero":
+        return np.zeros((R, K))
+    if kind == "one_large":                # all small but one
+        w = np.full((R, K), 0.5)
+        w[np.arange(R), rng.integers(0, K, R)] = 0.5 * K
+        return w
+    raise ValueError(kind)
+
+
+@pytest.mark.parametrize("kind", ["gamma", "onehot", "cascade", "equal",
+                                  "zipf", "zero", "one_large"])
+@pytest.mark.parametrize("R,K", [(1, 37), (64, 37), (3, 128), (64, 129),
+                                 (1, 5000), (4, 1000)])
+def test_windowed_sweep_matches_sequential_oracle(R, K, kind):
+    """The windowed sweep of ``build_alias_ref`` gives the tables of the
+    gather-per-step Walker loop bit for bit: K below, at and just above the
+    window width, K ≫ the width, one row and many."""
+    rng = np.random.default_rng(R * 7919 + K)
+    w = jnp.asarray(_rows(kind, R, K, rng).astype(np.float32))
+    wn, order, _, ns = (np.asarray(a) for a in alias_ops._prepare(w))
+    prob, alias = alias_ops.build_alias(w, force="ref")
+    p_want, a_want = _walker_oracle(wn, order, ns)
+    np.testing.assert_array_equal(np.asarray(prob), p_want)
+    np.testing.assert_array_equal(np.asarray(alias), a_want)
+
+
 @pytest.mark.parametrize("R,K", [(1, 1), (3, 40), (6, 1000)])
 def test_prepare_partition_is_the_stable_argsort(R, K):
     """The prefix-sum partition in ``_prepare`` == a stable argsort of the
     small/large flags (smalls in index order, then larges)."""
     w = jnp.asarray(RNG.gamma(0.3, 1.0, (R, K)).astype(np.float32))
     w = w.at[0, :K // 2].set(0.0)          # zero-weight slots are smalls
-    wn, order, ns = alias_ops._prepare(w)
+    wn, order, _, ns = alias_ops._prepare(w)
     large = np.asarray(wn) >= 1.0
     np.testing.assert_array_equal(
         np.asarray(order), np.argsort(large, axis=-1, kind="stable"))
     np.testing.assert_array_equal(np.asarray(ns), (~large).sum(axis=-1))
+
+
+@pytest.mark.parametrize("R,K", [(1, 1), (3, 40), (6, 1000)])
+def test_prepare_stream_weights_follow_order(R, K):
+    """``wn_ord`` from the ordering scatter == ``wn`` gathered by ``order``."""
+    w = jnp.asarray(RNG.gamma(0.3, 1.0, (R, K)).astype(np.float32))
+    wn, order, wn_ord, _ = (np.asarray(a) for a in alias_ops._prepare(w))
+    np.testing.assert_array_equal(
+        wn_ord, np.take_along_axis(wn, order, axis=-1))
 
 
 @pytest.mark.parametrize("shape", [(4, 64), (2, 3, 32)])

@@ -82,6 +82,33 @@ def test_alias_build_compiles_at_chip_share(sds):
     _fits(c)
 
 
+@pytest.mark.parametrize("layout", ["ring", "word_sharded"])
+def test_word_tables_over_sharded_phi_sweep_each_chips_rows(topo, layout):
+    """A Φ split across the 2×2 chips by rows (the 4-chip ring, or the
+    word-sharded data 2 × model 2 layout) keeps the Walker sweep's windows
+    per chip: each chip gathers its windows from its own rows' blocks,
+    never from blocks of all the rows."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+
+    from repro.core import sparse
+    from repro.dist import sharding as shd
+
+    k = 1000                               # nb = ⌈k/128⌉ + 1 = 9 blocks
+    nb = -(-k // 128) + 1
+    if layout == "ring":
+        grid, shape, spec = (4, 1), (4, V, k), shd.ring_spec()
+    else:
+        grid, shape, spec = (2, 2), (2, 2 * V, k), shd.wshard_spec()
+    mesh = Mesh(np.array(topo.devices).reshape(grid), shd.RING_AXES)
+    phi = jax.ShapeDtypeStruct(shape, jnp.float32,
+                               sharding=NamedSharding(mesh, spec))
+    hlo = sparse.build_alias_word.lower(phi).compile().as_text()
+    assert f"[{V},{nb},128]" in hlo
+    assert f"[{4 * V},{nb},128]" not in hlo
+    assert f"[{4 * V * nb},128]" not in hlo
+
+
 def test_alias_mh_resample_compiles_at_chip_share(sds):
     T, D, cap = 4096, 1024, 16
     f = jax.jit(alias_ops.mh_resample,
