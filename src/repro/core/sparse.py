@@ -26,7 +26,8 @@ Table builders (:func:`make_word_tables`, :func:`make_alpha_table`) produce
 the stale proposal tables the MH probe corrects against; the Trainer rebuilds
 them at aggregation boundaries from merged Φ. Each table's Walker build is a
 program of its own name (``build_alias_word``, ``build_alias_alpha``), so a
-device trace tells the two apart.
+device trace tells the two apart. The word tables are built per shard, in
+Φ's own layout on the mesh: each chip builds the rows it holds.
 """
 from __future__ import annotations
 
@@ -216,16 +217,39 @@ def apply_deltas(topic, count, d, z_old, z_new, valid):
 def _table_build(name: str):
     """``alias_ops.alias_tables`` as a program named ``name``: the traced
     function of ``alias_ops.build_alias``, so the same HLO, under a name of
-    its own."""
-    def build(weights, *, force: str | None = None):
-        return alias_ops.alias_tables(weights, force=force)
+    its own.
+
+    Given a ``mesh`` and the ``spec`` of the weights' layout on it, the
+    build runs under ``shard_map`` with that spec in and out: each chip
+    normalises, partitions and sweeps only the rows it holds, and the
+    tables come out in the weights' layout. Without, it is one program
+    over the whole array, and the partitioner gathers every row of a
+    sharded input onto every chip (the row loops of the build cannot be
+    split)."""
+    def build(weights, *, force: str | None = None, mesh=None, spec=None):
+        tables = partial(alias_ops.alias_tables, force=force)
+        if mesh is None:
+            return tables(weights)
+        return jax.shard_map(tables, mesh=mesh, in_specs=(spec,),
+                             out_specs=(spec, spec),
+                             check_vma=False)(weights)
 
     build.__name__ = build.__qualname__ = name
-    return jax.jit(build, static_argnames=("force",))
+    return jax.jit(build, static_argnames=("force", "mesh", "spec"))
 
 
 build_alias_word = _table_build("build_alias_word")
 build_alias_alpha = _table_build("build_alias_alpha")
+
+
+def _layout(x):
+    """``(mesh, spec)`` of an array laid out on a mesh, else ``(None,
+    None)`` (one device, or a tracer inside another program)."""
+    sharding = (None if isinstance(x, jax.core.Tracer)
+                else getattr(x, "sharding", None))
+    if isinstance(sharding, jax.sharding.NamedSharding):
+        return sharding.mesh, sharding.spec
+    return None, None
 
 
 def make_word_tables(phi, psi, beta, vocab_size: int, *,
@@ -235,14 +259,17 @@ def make_word_tables(phi, psi, beta, vocab_size: int, *,
     phi [..., rows, K] int32, psi [..., K] int32 (leading pod/shard dims ride
     along) → (wq, wp, wa) with wq = (φ+β)/(ψ+Vβ) — the LightLDA word
     proposal including its denominator, so staleness covers both factors.
+    A Φ laid out on a mesh is built per shard in that layout: each chip
+    builds the tables of its own rows (``_table_build``).
     """
+    mesh, spec = _layout(phi)
     beta = jnp.float32(beta)
     psi_b = psi.astype(jnp.float32)
     while psi_b.ndim < phi.ndim:
         psi_b = jnp.expand_dims(psi_b, -2)
     wq = (phi.astype(jnp.float32) + beta) / (
         psi_b + jnp.float32(vocab_size) * beta)
-    wp, wa = build_alias_word(wq, force=force)
+    wp, wa = build_alias_word(wq, force=force, mesh=mesh, spec=spec)
     return wq, wp, wa
 
 

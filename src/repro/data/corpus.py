@@ -291,6 +291,21 @@ def assign_segments(n_docs: int, n_segments: int, seed: int = 0) -> np.ndarray:
     return seg_of
 
 
+def stable_cap_multiple(tokens_per_block: float, n_blocks: int) -> int:
+    """The multiple a ring's sub-block capacity is rounded up to.
+
+    With more than one sub-block the capacity is the fullest block's count,
+    which moves with the corpus and the document shuffle by a fraction of a
+    percent; rounded to the power of two between 1/64 and 1/32 of the mean
+    block, corpora of one size share one static shape, so their programs
+    compile once, for at most ~3% sentinel slots. One block holds the whole corpus, whose size
+    does not move: 8, as ``shard_corpus``'s default.
+    """
+    if n_blocks <= 1:
+        return 8
+    return max(8, 1 << max(0, int(tokens_per_block).bit_length() - 6))
+
+
 def segment_corpus(
     corpus: Corpus, n_segments: int, n_data_shards: int, n_vocab_shards: int,
     n_topics: int, seed: int = 0, n_model_shards: int = 1,
@@ -301,11 +316,14 @@ def segment_corpus(
     across segments (re-derived from the full-corpus frequency), and one common
     static shape (cap, docs_per_shard): the ring epoch is compiled once and
     every segment swap reuses it — segment count is a memory knob, never a
-    recompile.
+    recompile. The cap is rounded up by :func:`stable_cap_multiple`.
     """
+    mult = stable_cap_multiple(
+        corpus.n_tokens / (n_segments * n_data_shards * n_vocab_shards),
+        n_data_shards * n_vocab_shards)
     if n_segments == 1:
         return Segments([shard_corpus(corpus, n_data_shards, n_vocab_shards,
-                                      n_topics, seed,
+                                      n_topics, seed, cap_multiple=mult,
                                       n_model_shards=n_model_shards)])
     # one global vocab placement for every segment (phi shards must be stable)
     freq = np.bincount(corpus.word_ids, minlength=corpus.vocab_size)
@@ -324,7 +342,7 @@ def segment_corpus(
     # shape probe (vectorized counting only), then ONE build per segment
     probe = [
         shard_corpus(s, n_data_shards, n_vocab_shards, n_topics, seed + g,
-                     placement=placement, probe_only=True,
+                     placement=placement, probe_only=True, cap_multiple=mult,
                      n_model_shards=n_model_shards)
         for g, s in enumerate(subs)
     ]
@@ -333,7 +351,7 @@ def segment_corpus(
     return Segments([
         shard_corpus(s, n_data_shards, n_vocab_shards, n_topics, seed + g,
                      placement=placement, min_cap=cap, min_docs_per_shard=dps,
-                     uids=u, n_model_shards=n_model_shards)
+                     uids=u, cap_multiple=mult, n_model_shards=n_model_shards)
         for g, (s, u) in enumerate(zip(subs, guids))
     ])
 

@@ -432,11 +432,18 @@ class Trainer:
         from repro.core import dedup
 
         return dedup.topic_count_histogram(
-            jnp.asarray(np.asarray(dl).reshape(-1)),
+            jnp.asarray(self._global_docs(np.asarray(dl)).reshape(-1)),
             jnp.asarray(np.asarray(z).reshape(-1)),
             jnp.asarray(np.asarray(valid).reshape(-1)),
             self.ring_cfg.docs_per_shard * self.config.ring_size,
             self.config.n_topics)
+
+    def _global_docs(self, dl):
+        """[S, M, cap] doc ids local to each data shard → ids unique over
+        the ring (stack s holds data shard s at every epoch boundary), so
+        the Ω histogram never merges two shards' documents."""
+        shard = np.arange(dl.shape[0], dtype=np.int32)[:, None, None]
+        return dl + shard * np.int32(self.ring_cfg.docs_per_shard)
 
     def _fold_segment_omega(self, seg) -> None:
         """Ω_kn part for one just-committed segment (its z is final for this
@@ -467,11 +474,20 @@ class Trainer:
         """Refresh the alias sampler's stale proposal state from the current
         (phi, psi, α). ``word=False`` refreshes only the (cheap) α table —
         used when α moved but Φ is mid-window."""
+        import jax
+
         from repro.core import sparse
 
-        phi, psi = self.state[0], self.state[1]
         if word or self._tables is None:
+            # Φ and Ψ in the epoch's layout first, and kept as the state: a
+            # state built on the host or restored from a checkpoint sits on
+            # one chip, which would then make the proposal weights of the
+            # whole Φ, and hold the whole Φ beside its shards until the
+            # first epoch replaced it
+            phi, psi = jax.device_put(self.state[:2], self._epoch_in[:2])
+            self.state = (phi, psi) + tuple(self.state[2:])
             with spans.span("peacock.train.tables.word"):
+                # built per shard, in Φ's layout on the mesh
                 wq, wp, wa = sparse.make_word_tables(
                     phi, psi, self.beta, self.ring_cfg.vocab_size)
         else:
@@ -570,7 +586,8 @@ class Trainer:
             dl = self.state[3][0] if multi else self.state[3]
             z = self.state[5][0] if multi else self.state[5]
             omega = dedup.topic_count_histogram(
-                dl.reshape(-1), z.reshape(-1), (wl >= 0).reshape(-1),
+                self._global_docs(dl).reshape(-1), z.reshape(-1),
+                (wl >= 0).reshape(-1),
                 self.ring_cfg.docs_per_shard * cfg.ring_size, cfg.n_topics)
         if self._doc_len_hist is None:
             self._doc_len_hist = dedup.doc_length_histogram(
@@ -747,5 +764,20 @@ class Trainer:
             "publish_s_mean": mean(pub_s),
             "n_publishes": len(pub_s),
             "ll_final": ll[-1] if ll else None,
+            "ring": self._ring_record(),
             "spans": spans.recorder().totals(since=self._spans_from),
         }
+
+    def _ring_record(self) -> Optional[dict]:
+        """The ring's geometry (of pod 0, or of the first segment when
+        streamed): rounds per epoch, sub-block capacity, stack slots
+        (M·M·cap: rounds × sub-blocks × cap, sentinels included) and the
+        valid tokens among them. Slots beyond the tokens are sentinels,
+        sampled and masked: MH work spent on nothing."""
+        if self.sc0 is None or self.ring_cfg is None:
+            return None
+        wl = np.asarray(self.sc0.word_local)
+        return {"rounds": int(self.ring_cfg.n_rounds),
+                "cap": int(self.ring_cfg.cap),
+                "slots": int(wl.size),
+                "tokens": int(np.count_nonzero(wl >= 0))}

@@ -57,6 +57,38 @@ def test_segment_corpus_common_static_shapes_and_global_uids():
                 == np.where(valid)[1]).all()
 
 
+def _flat_corpus(seed, n_tokens, n_docs, vocab_size=400):
+    rng = np.random.default_rng(seed)
+    docs = np.sort(rng.integers(0, n_docs, n_tokens)).astype(np.int32)
+    words = rng.integers(0, vocab_size, n_tokens).astype(np.int32)
+    return corpus_mod.Corpus(words, docs, n_docs, vocab_size)
+
+
+@pytest.mark.parametrize("n_segments", [1, 2])
+def test_ring_cap_rounds_up_to_a_stable_multiple(n_segments):
+    corpus = _flat_corpus(5, 20_000, 4_000)
+    segs = corpus_mod.segment_corpus(corpus, n_segments, 4, 2, 8,
+                                     seed=1).segments
+    mult = corpus_mod.stable_cap_multiple(
+        corpus.n_tokens / (n_segments * 8), 8)
+    assert mult == (64 if n_segments == 1 else 32)
+    fullest = max(int((np.asarray(sc.word_local)[s, m] >= 0).sum())
+                  for sc in segs for s in range(4) for m in range(2))
+    cap = segs[0].word_local.shape[-1]
+    assert cap % mult == 0 and fullest <= cap < fullest + mult
+
+
+def test_ring_cap_is_one_shape_across_seeds_of_one_size():
+    caps = {corpus_mod.segment_corpus(_flat_corpus(seed, 1 << 18, 40_000),
+                                      1, 4, 2, 8, seed=seed)
+            .segments[0].word_local.shape[-1] for seed in range(3)}
+    assert caps == {33 * 1024}, caps
+    # one block holds the whole corpus: its cap is the corpus, as before
+    one = corpus_mod.segment_corpus(_flat_corpus(0, 1001, 300), 1, 1, 1, 8)
+    assert corpus_mod.stable_cap_multiple(1001, 1) == 8
+    assert one.segments[0].word_local.shape[-1] == 1008
+
+
 def test_segment_order_is_a_seeded_permutation():
     o1 = segment_order(5, epoch=3, seed=11)
     o2 = segment_order(5, epoch=3, seed=11)

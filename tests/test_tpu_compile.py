@@ -109,6 +109,63 @@ def test_word_tables_over_sharded_phi_sweep_each_chips_rows(topo, layout):
     assert f"[{4 * V * nb},128]" not in hlo
 
 
+@pytest.fixture(scope="module")
+def one_chip_word_build(sds):
+    """The compiled one-chip word-table build at [1, 821, K]: the ring
+    state's shape on one chip of the paper's 256-chip ring."""
+    from repro.core import sparse
+
+    return sparse.build_alias_word.lower(sds((1, V, K), jnp.float32)).compile()
+
+
+def _strip(hlo: str) -> str:
+    """An HLO text without module name, metadata and frontend attributes
+    (which name the mesh)."""
+    import re
+
+    hlo = re.sub(r"\n(FileNames|FunctionNames|FileLocations|StackFrames)\n"
+                 r".*?(?=\n\n|$)", "", hlo, flags=re.S)
+    hlo = re.sub(r",? ?metadata=\{[^}]*\}", "", hlo)
+    hlo = re.sub(r",? ?frontend_attributes=\{[^{}]*(\{[^{}]*\}[^{}]*)*\}",
+                 "", hlo)
+    return re.sub(r"HloModule \S+,", "HloModule m,", hlo)
+
+
+@pytest.mark.parametrize("layout", ["one_chip", "ring", "word_sharded"])
+def test_word_tables_built_per_shard_hold_no_gathered_phi(
+        topo, one_chip_word_build, layout):
+    """The word-table build in Φ's own layout (``shard_map``): on a 1×1 mesh
+    it is the one-chip program; over the 2×2 chips at 4 × 821 rows, in the
+    4-chip ring and in the word-sharded layout, no chip gathers Φ or any
+    [3284, ·] plane, and each chip's temporaries are those of the one-chip
+    build within 10%."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+
+    from repro.core import sparse
+    from repro.dist import sharding as shd
+
+    grid, shape, spec = {
+        "one_chip": ((1, 1), (1, V, K), shd.ring_spec()),
+        "ring": ((4, 1), (4, V, K), shd.ring_spec()),
+        "word_sharded": ((2, 2), (2, 2 * V, K), shd.wshard_spec()),
+    }[layout]
+    n = grid[0] * grid[1]
+    mesh = Mesh(np.array(topo.devices[:n]).reshape(grid), shd.RING_AXES)
+    phi = jax.ShapeDtypeStruct(shape, jnp.float32,
+                               sharding=NamedSharding(mesh, spec))
+    c = sparse.build_alias_word.lower(phi, mesh=mesh, spec=spec).compile()
+    hlo = c.as_text()
+    if layout == "one_chip":
+        assert _strip(hlo) == _strip(one_chip_word_build.as_text())
+        return
+    assert "all-gather" not in hlo
+    assert f"[{4 * V}," not in hlo
+    base = one_chip_word_build.memory_analysis().temp_size_in_bytes
+    temp = _fits(c)
+    assert abs(temp - base) <= 0.1 * base, (temp, base)
+
+
 def test_alias_mh_resample_compiles_at_chip_share(sds):
     T, D, cap = 4096, 1024, 16
     f = jax.jit(alias_ops.mh_resample,
