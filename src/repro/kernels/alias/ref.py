@@ -72,7 +72,7 @@ def _sweep_step(carry, _, small_at, large_at, ns, n_topics):
 
     The step only carries six values per row and EMITS its finalized
     (slot, prob, alias) triple as a scan output — the [K] tables materialize
-    in one scatter per row after the scan, so the sweep is O(K) per row, not
+    in one sort along K after the scan, so the sweep is O(K) per row, not
     the O(K²) a carried-array copy per step would cost."""
     i, j, cur, curw, pend, pendw = carry
     K = n_topics
@@ -183,19 +183,6 @@ def _sweep(order, wn_ord, ns):
     return tuple(o.reshape(-1, R)[:K].T for o in out)
 
 
-def _write_row(slots, vals, alis):
-    """Scatter one row's finalized triples into its (prob, alias) tables."""
-    K = slots.shape[0]
-    # every live step finalizes exactly one slot; idle tail steps emit
-    # slot = -1 → redirected out of bounds and dropped (defaults: prob 1,
-    # alias self — the same values a live finalize would have written)
-    slot_w = jnp.where(slots >= 0, slots, K)
-    prob = jnp.ones((K,), jnp.float32).at[slot_w].set(vals, mode="drop")
-    alias = jnp.arange(K, dtype=jnp.int32).at[slot_w].set(
-        alis.astype(jnp.int32), mode="drop")
-    return prob, alias
-
-
 def build_alias_ref(order, wn_ord, ns):
     """Batched alias construction. order [R, K] small/large partition order,
     wn_ord [R, K] the normalized (mean 1) weights in that order, ns [R] small
@@ -205,11 +192,19 @@ def build_alias_ref(order, wn_ord, ns):
     The sweeps run side by side across rows, each step reading its operands
     from C-wide windows of (order, wn_ord) taken every C steps, not from the
     [R, K] rows: at R = 821, K = 10⁵ the four per-step [821]-element gathers
-    took ~44 µs of a 47 µs step on a TPU v5e. The table writes run row by
-    row, since one [R, K] scatter costs the TPU compiler ~25 s at
-    R·K ≈ 10⁸ and a loop of [K] scatters about 2 s."""
-    steps = _sweep(order, wn_ord, ns)
-    return jax.lax.map(lambda t: _write_row(*t), steps)
+    took ~44 µs of a 47 µs step on a TPU v5e.
+
+    The K kept steps of a row finalize each of its slots exactly once (every
+    step finalizes one slot while any remains, and no slot twice), so their
+    ``slot`` outputs are a permutation of [0, K). The tables are therefore
+    one row-batched sort along K keyed by ``slot`` (unique, so not stable)
+    that carries (prob, alias): the same arrays, bit for bit, as writing
+    each step's values at its slot, without the per-index writes of a [K]
+    scatter (~5.9 ns per index on a TPU v5e)."""
+    slot, val, ali = _sweep(order, wn_ord, ns)
+    _, prob, alias = jax.lax.sort((slot, val, ali), dimension=1,
+                                  is_stable=False, num_keys=1)
+    return prob, alias
 
 
 # --------------------------------------------------------------- probe ------

@@ -13,6 +13,7 @@ import pytest
 
 from repro.core import sparse
 from repro.kernels.alias import ops as alias_ops
+from repro.kernels.alias import ref as alias_ref
 from repro.kernels.gibbs import ops as gibbs_ops
 
 pytestmark = pytest.mark.kernels
@@ -112,6 +113,49 @@ def test_windowed_sweep_matches_sequential_oracle(R, K, kind):
     wn, order, _, ns = (np.asarray(a) for a in alias_ops._prepare(w))
     prob, alias = alias_ops.build_alias(w, force="ref")
     p_want, a_want = _walker_oracle(wn, order, ns)
+    np.testing.assert_array_equal(np.asarray(prob), p_want)
+    np.testing.assert_array_equal(np.asarray(alias), a_want)
+
+
+# the grid of test_windowed_sweep_matches_sequential_oracle
+GRID_KINDS = ["gamma", "onehot", "cascade", "equal", "zipf", "zero",
+              "one_large"]
+GRID_SHAPES = [(1, 37), (64, 37), (3, 128), (64, 129), (1, 5000), (4, 1000)]
+
+
+def _swept(R, K, kind):
+    """Weights of one family and the trimmed sweep's per-step (slot, prob,
+    alias) outputs for them, each [R, K]."""
+    rng = np.random.default_rng(R * 7919 + K)
+    w = jnp.asarray(_rows(kind, R, K, rng).astype(np.float32))
+    _, order, wn_ord, ns = alias_ops._prepare(w)
+    return w, tuple(np.asarray(a) for a in alias_ref._sweep(order, wn_ord,
+                                                            ns))
+
+
+@pytest.mark.parametrize("kind", GRID_KINDS)
+@pytest.mark.parametrize("R,K", GRID_SHAPES)
+def test_sweep_finalizes_each_slot_once(R, K, kind):
+    """The K kept steps of every row finalize each slot exactly once: their
+    ``slot`` outputs are a permutation of [0, K), the premise of writing the
+    tables by a sort keyed by ``slot``."""
+    _, (slot, _, _) = _swept(R, K, kind)
+    np.testing.assert_array_equal(
+        np.sort(slot, axis=-1), np.broadcast_to(np.arange(K), (R, K)))
+
+
+@pytest.mark.parametrize("kind", GRID_KINDS)
+@pytest.mark.parametrize("R,K", GRID_SHAPES)
+def test_sorted_table_write_matches_scatter_oracle(R, K, kind):
+    """``build_alias`` == each step's (prob, alias) written at its slot, as
+    a NumPy scatter, bit for bit; a slot no step wrote stays NaN / −1."""
+    w, (slot, val, ali) = _swept(R, K, kind)
+    rows = np.arange(R)[:, None]
+    p_want = np.full((R, K), np.nan, np.float32)
+    a_want = np.full((R, K), -1, np.int32)
+    p_want[rows, slot] = val
+    a_want[rows, slot] = ali
+    prob, alias = alias_ops.build_alias(w, force="ref")
     np.testing.assert_array_equal(np.asarray(prob), p_want)
     np.testing.assert_array_equal(np.asarray(alias), a_want)
 

@@ -76,10 +76,35 @@ def test_gibbs_kernel_compiles_at_full_width(sds):
     _fits(c)
 
 
+def _loop_computations(hlo: str) -> dict:
+    """The computations of an HLO text that run inside a while loop: every
+    loop's body and what it calls, by name."""
+    import re
+
+    comps = dict(re.findall(r"^(?:ENTRY )?%([\w.\-]+) [^\n]*\{\n(.*?)^\}$",
+                            hlo, re.M | re.S))
+    todo, seen = re.findall(r"\bbody=%([\w.\-]+)", hlo), set()
+    while todo:
+        name = todo.pop()
+        if name in comps and name not in seen:
+            seen.add(name)
+            todo += re.findall(r"%([\w.\-]+)", comps[name])
+    return {name: comps[name] for name in seen}
+
+
 def test_alias_build_compiles_at_chip_share(sds):
+    """The build writes its permutations by row-batched sorts along K: no
+    scatter, no sort inside a loop over rows, and temporaries within 5% of
+    1.738 GB, those of a build by row loops of [K] scatters."""
     c = alias_ops.build_alias.lower(sds((V, K), jnp.float32),
                                    force=alias_ops.TPU_MODE).compile()
-    _fits(c)
+    temp = _fits(c)
+    hlo = c.as_text()
+    assert "scatter(" not in hlo
+    assert hlo.count(" sort(") == 2
+    assert not [name for name, body in _loop_computations(hlo).items()
+                if " sort(" in body]
+    assert abs(temp - 1.738e9) <= 0.05 * 1.738e9, temp
 
 
 @pytest.mark.parametrize("layout", ["ring", "word_sharded"])
