@@ -334,7 +334,6 @@ def main(argv=None) -> None:
 
     from repro import kernels
     from repro.configs import peacock_lda as pl
-    from repro.kernels.alias import ops as alias_ops
 
     devices = jax.devices()
     dev = devices[0]
@@ -354,8 +353,7 @@ def main(argv=None) -> None:
     log(f"device: {dev.platform} {dev.device_kind} x{len(devices)}; "
         f"compile cache {cache}")
     log(f"kernels: gibbs_argmax -> {kernels.kernel_mode()} (compiled "
-        f"Pallas), alias build_alias/mh_resample -> "
-        f"{alias_ops.dispatch_mode()} (jnp under XLA)")
+        f"Pallas), alias build_alias/mh_resample -> XLA programs")
     if kernels.kernel_mode() != "pallas":
         raise RuntimeError("the Gibbs op does not dispatch to its kernel")
 

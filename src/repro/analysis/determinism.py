@@ -41,7 +41,7 @@ from repro.dist.analysis import _as_jaxpr, _sub_jaxprs
 
 # scatter variants whose collision order XLA does not pin; -add/-mul are the
 # accumulating forms the bitwise contract cares about (plain scatter with
-# unique indices — the unsort in kernels/alias/ops.mh_resample — is fine)
+# unique indices is fine)
 _SCATTER_ACCUM_PRIMS = {"scatter-add", "scatter-mul", "scatter-min",
                         "scatter-max"}
 

@@ -218,10 +218,7 @@ def run_vmem_pass(session: AbstractSession) -> PassResult:
     P = max(1, cfg.model_shards)
     plans = vmem.repo_kernel_plans(
         n_topics=cfg.n_topics, rows_per_device=cfg.rows_per_shard // P,
-        docs_per_shard=cfg.docs_per_shard,
-        doc_topic_cap=cfg.doc_topic_cap,
-        package_len=min(cfg.package_len, 256) or 256,
-        n_mh=cfg.n_mh, sampler=cfg.sampler)
+        package_len=min(cfg.package_len, 256) or 256, sampler=cfg.sampler)
     findings = vmem.check_vmem(plans)
     return PassResult("vmem", findings, time.monotonic() - t0)
 

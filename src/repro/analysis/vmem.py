@@ -2,11 +2,11 @@
 
 TPU Pallas kernels live or die on the ~16 MB/core VMEM budget, and the repo's
 kernels declare their VMEM residency entirely through ``pl.BlockSpec``s /
-``pltpu.VMEM`` scratch (``kernels/{gibbs,alias,embedding_bag}``). This module
-turns the capacity *comment* in ``kernels/alias/kernel.py`` into an enforced
-check: it captures the exact specs a kernel wrapper constructs at a given
-geometry and sums buffer bytes against the budget — at launch, not three
-hours into an epoch when a (K, cap, tile) geometry finally overflows.
+``pltpu.VMEM`` scratch (``kernels/{gibbs,embedding_bag}``). This module turns
+that residency into an enforced check: it captures the exact specs a kernel
+wrapper constructs at a given geometry and sums buffer bytes against the
+budget — at launch, not three hours into an epoch when a (K, cap, tile)
+geometry finally overflows.
 
 How capture works: ``pl.pallas_call`` is temporarily replaced with a recorder
 while the wrapper is traced under ``jax.eval_shape`` — the wrapper's own
@@ -333,8 +333,7 @@ def check_vmem(plans: Sequence[KernelPlan],
                 f"{plan.vmem_bytes / 1e6:.1f} MB VMEM > "
                 f"{budget_bytes / 1e6:.1f} MB budget at this geometry — "
                 f"shrink the tile or move the big operand to "
-                f"MemorySpace.ANY (HBM-resident path, "
-                f"kernels/alias/kernel.py):\n{plan.table()}",
+                f"MemorySpace.ANY (HBM-resident):\n{plan.table()}",
                 location=plan.kernel, **data))
         else:
             findings.append(info(
@@ -350,18 +349,17 @@ def check_vmem(plans: Sequence[KernelPlan],
 
 
 def repo_kernel_plans(n_topics: int, rows_per_device: int,
-                      docs_per_shard: int, doc_topic_cap: int,
-                      package_len: int, n_mh: int = 4,
-                      sampler: str = "dense",
+                      package_len: int, sampler: str = "dense",
                       embedding_dim: int = 64,
                       bag_fields: int = 8) -> List[KernelPlan]:
     """Plans for the kernels a session with this geometry would dispatch.
 
-    ``sampler="dense"`` plans the gibbs plane-scan kernel; ``"alias"`` plans
-    the Walker build + MH probe kernels (whose whole-table VMEM binding is
-    the capacity cliff this check enforces). The embedding-bag kernel is
-    always planned — its table rides HBM so it is geometry-insensitive, and
-    including it keeps the audit exhaustive over ``kernels/*``.
+    ``sampler="dense"`` plans the gibbs plane-scan kernel. ``"alias"`` plans
+    no sampler kernel: the alias build and MH probe (``kernels/alias/ops.py``)
+    are XLA programs, which hold no BlockSpecs for this planner to price.
+    The embedding-bag kernel is always planned — its table rides HBM so it
+    is geometry-insensitive, and including it keeps the audit exhaustive
+    over the Pallas kernels in ``kernels/*``.
     """
     import jax
     import jax.numpy as jnp
@@ -369,32 +367,10 @@ def repo_kernel_plans(n_topics: int, rows_per_device: int,
     sds = jax.ShapeDtypeStruct
     K = int(n_topics)
     rows = max(1, int(rows_per_device))
-    D = max(1, int(docs_per_shard))
-    cap = max(1, int(doc_topic_cap or K))
     T = max(8, int(package_len))
     plans: List[KernelPlan] = []
 
-    if sampler == "alias":
-        from repro.kernels.alias import kernel as ak
-
-        plans += plan_fn(
-            lambda wn, order, ns: unjitted(ak.alias_build_pallas)(
-                wn, order, ns),
-            sds((rows, K), jnp.float32), sds((rows, K), jnp.int32),
-            sds((rows,), jnp.int32))
-        plans += plan_fn(
-            lambda *a: unjitted(ak.mh_resample_pallas)(
-                *a, vocab_size=rows, n_mh=n_mh),
-            sds((rows, K), jnp.int32), sds((K,), jnp.int32),
-            sds((D, cap), jnp.int32), sds((D, cap), jnp.int32),
-            sds((rows, K), jnp.float32), sds((rows, K), jnp.float32),
-            sds((rows, K), jnp.int32), sds((K,), jnp.float32),
-            sds((K,), jnp.float32), sds((K,), jnp.int32),
-            sds((T,), jnp.int32), sds((T,), jnp.int32),
-            sds((T,), jnp.int32), sds((T,), jnp.uint32),
-            sds((), jnp.uint32), sds((), jnp.float32),
-            sds((), jnp.float32))
-    else:
+    if sampler != "alias":
         from repro.kernels.gibbs import kernel as gk
 
         plans += plan_fn(

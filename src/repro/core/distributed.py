@@ -190,7 +190,7 @@ def _sample_subblock_mh(phi, psi, pairs, w, d, z, uid, alpha, beta, seed,
                 phi, psi, tp, ct, tables.wq, tables.wp, tables.wa, alpha,
                 tables.ap, tables.aa, w_s, d_s, z, uid.astype(jnp.uint32),
                 jnp.asarray(seed, jnp.uint32), beta, cfg.vocab_size,
-                cfg.n_mh, force="pallas" if cfg.use_kernel else None)
+                cfg.n_mh)
         z_new = jnp.where(valid, z_new, z)
         delta = valid.astype(jnp.int32)
         phi = phi.at[w_s, z].add(-delta).at[w_s, z_new].add(delta)

@@ -32,8 +32,9 @@ sampler, which draws every token of a round against the round-start
 snapshot (one package per round); and it replays the replicated layout
 (model_shards = 1) only. The Walker tables come from the kernel layer's own
 ``alias_tables`` and the uniforms from ``core/prng.py``: this reference
-checks the ring's schedule, not the table build or the hash, which have
-references of their own (``kernels/alias/ref.py``).
+checks the ring's schedule, not the table build or the hash, which are
+checked on their own (the build against a sequential NumPy Walker loop in
+``tests/test_kernels_alias.py``).
 
 Besides each draw it returns the token's margin: the smallest relative gap
 between two numbers any of its MH steps compared. A draw that differs from
@@ -190,7 +191,7 @@ def word_tables(phi_v, psi, beta, vocab_size: int):
     beta = np.float32(beta)
     wq = (phi_v.astype(np.float32) + beta) / (
         psi.astype(np.float32)[None, :] + np.float32(vocab_size) * beta)
-    wp, wa = alias_ops.build_alias(jnp.asarray(wq[None]), force="ref")
+    wp, wa = alias_ops.build_alias(jnp.asarray(wq[None]))
     return wq, np.asarray(wp[0]), np.asarray(wa[0])
 
 
@@ -216,7 +217,7 @@ def ring_epoch(layout: RingLayout, z, alpha, beta, seed: int,
     with jax.default_matmul_precision("highest"):
         alpha_sum = np.float32(jnp.sum(jnp.asarray(alpha)))
         ap, aa = (np.asarray(a[0]) for a in alias_ops.build_alias(
-            jnp.asarray(alpha[None]), force="ref"))
+            jnp.asarray(alpha[None])))
         mine = [np.nonzero(layout.vocab == v)[0] for v in range(M)]
 
         def phi_of(v):

@@ -226,16 +226,16 @@ def _table_build(name: str):
     over the whole array, and the partitioner gathers every row of a
     sharded input onto every chip (the row loops of the build cannot be
     split)."""
-    def build(weights, *, force: str | None = None, mesh=None, spec=None):
-        tables = partial(alias_ops.alias_tables, force=force)
+    def build(weights, *, mesh=None, spec=None):
         if mesh is None:
-            return tables(weights)
-        return jax.shard_map(tables, mesh=mesh, in_specs=(spec,),
+            return alias_ops.alias_tables(weights)
+        return jax.shard_map(alias_ops.alias_tables, mesh=mesh,
+                             in_specs=(spec,),
                              out_specs=(spec, spec),
                              check_vma=False)(weights)
 
     build.__name__ = build.__qualname__ = name
-    return jax.jit(build, static_argnames=("force", "mesh", "spec"))
+    return jax.jit(build, static_argnames=("mesh", "spec"))
 
 
 build_alias_word = _table_build("build_alias_word")
@@ -252,8 +252,8 @@ def _layout(x):
     return None, None
 
 
-def make_word_tables(phi, psi, beta, vocab_size: int, *,
-                     force: str | None = None) -> Tuple[jax.Array, ...]:
+def make_word_tables(phi, psi, beta,
+                     vocab_size: int) -> Tuple[jax.Array, ...]:
     """Stale word-proposal tables from a Φ snapshot.
 
     phi [..., rows, K] int32, psi [..., K] int32 (leading pod/shard dims ride
@@ -269,29 +269,27 @@ def make_word_tables(phi, psi, beta, vocab_size: int, *,
         psi_b = jnp.expand_dims(psi_b, -2)
     wq = (phi.astype(jnp.float32) + beta) / (
         psi_b + jnp.float32(vocab_size) * beta)
-    wp, wa = build_alias_word(wq, force=force, mesh=mesh, spec=spec)
+    wp, wa = build_alias_word(wq, mesh=mesh, spec=spec)
     return wq, wp, wa
 
 
-def make_alpha_table(alpha, *, force: str | None = None):
+def make_alpha_table(alpha):
     """α alias table (ap [K] f32, aa [K] int32) — rebuilt whenever the Minka
     fixed point moves α (cheap: one K-row build)."""
-    ap, aa = build_alias_alpha(alpha[None, :].astype(jnp.float32),
-                               force=force)
+    ap, aa = build_alias_alpha(alpha[None, :].astype(jnp.float32))
     return ap[0], aa[0]
 
 
-def make_tables(phi, psi, alpha, beta, vocab_size: int, *,
-                force: str | None = None) -> AliasTables:
-    wq, wp, wa = make_word_tables(phi, psi, beta, vocab_size, force=force)
-    ap, aa = make_alpha_table(alpha, force=force)
+def make_tables(phi, psi, alpha, beta, vocab_size: int) -> AliasTables:
+    wq, wp, wa = make_word_tables(phi, psi, beta, vocab_size)
+    ap, aa = make_alpha_table(alpha)
     return AliasTables(wq, wp, wa, ap, aa)
 
 
 # ------------------------------------------------------------ block MH ------
 
 
-@partial(jax.jit, static_argnames=("vocab_size", "n_mh", "force"))
+@partial(jax.jit, static_argnames=("vocab_size", "n_mh"))
 def sample_block_mh(
     phi: jax.Array,          # [rows, K] int32
     psi: jax.Array,          # [K] int32
@@ -307,14 +305,13 @@ def sample_block_mh(
     vocab_size: int,
     tables: AliasTables,
     n_mh: int = 4,
-    force: str | None = None,
 ):
     """One alias-MH sweep over a token block — ``sample_block``'s sparse
     mirror. Returns (z_new, phi', psi', doc_topic', doc_count')."""
     z_new = alias_ops.mh_resample(
         phi, psi, doc_topic, doc_count, tables.wq, tables.wp, tables.wa,
         alpha, tables.ap, tables.aa, w, dloc, z, token_uid,
-        jnp.asarray(seed, jnp.uint32), beta, vocab_size, n_mh, force=force)
+        jnp.asarray(seed, jnp.uint32), beta, vocab_size, n_mh)
     one = jnp.ones_like(z)
     phi = phi.at[w, z].add(-one).at[w, z_new].add(one)
     psi = psi.at[z].add(-one).at[z_new].add(one)

@@ -1,8 +1,9 @@
-# Pallas TPU kernels for compute hot-spots (validated in interpret mode on CPU).
+# Kernel ops and their dispatch. The Gibbs and embedding-bag ops run a Pallas
+# kernel on TPU (interpret mode on CPU when forced); the alias ops are jnp
+# programs that XLA compiles on every backend (kernels/alias/ops.py).
 
 _MODES = (None, "pallas", "interpret", "ref")
 _on_tpu_cached = None          # backend probe result, computed once
-_default_mode = None           # config-pinned mode (TrainerConfig.kernel_mode)
 
 
 def on_tpu() -> bool:
@@ -22,33 +23,13 @@ def on_tpu() -> bool:
     return _on_tpu_cached
 
 
-def set_kernel_mode(mode) -> None:
-    """Pin the process-wide default dispatch mode for all kernel ops.
-
-    ``None`` restores backend autodetection (on TPU each op's compiled
-    default, ``ref`` elsewhere).
-    ``TrainerConfig.kernel_mode`` routes here so a session can force e.g.
-    ``interpret`` in CI or ``ref`` on an accelerator for A/B debugging.
-    """
-    global _default_mode
-    if mode not in _MODES:
-        raise ValueError(f"kernel mode must be one of {_MODES}, got {mode!r}")
-    _default_mode = mode
-
-
-def kernel_mode(force=None, *, tpu: str = "pallas") -> str:
-    """Resolve a dispatch mode: explicit ``force`` > pinned default > backend.
-
-    ``tpu`` is what an op runs on a TPU backend when nothing is forced or
-    pinned: ``"pallas"`` (its compiled kernel) unless the op's kernel cannot
-    run there (see ``kernels/alias/ops.py``). Elsewhere the default is
-    ``"ref"``.
-    """
+def kernel_mode(force=None) -> str:
+    """Resolve a dispatch mode: an explicit ``force`` first, then the
+    backend: ``"pallas"`` (the compiled kernel) on TPU, ``"ref"``
+    elsewhere."""
     if force is not None:
         if force not in _MODES:
             raise ValueError(
                 f"kernel mode must be one of {_MODES}, got {force!r}")
         return force
-    if _default_mode is not None:
-        return _default_mode
-    return tpu if on_tpu() else "ref"
+    return "pallas" if on_tpu() else "ref"

@@ -1,1 +1,1 @@
-# Sparsity-aware alias-table MH sampling kernels (DESIGN.md §9).
+# The alias-table build and MH probe of the alias sampler (DESIGN.md §9).

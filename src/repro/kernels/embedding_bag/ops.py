@@ -12,8 +12,7 @@ from repro.kernels.embedding_bag.ref import (
 def embedding_bag(table, ids, weights=None, combiner: str = "sum",
                   *, force: str | None = None):
     """Padded multi-hot lookup. force in {None, "pallas", "interpret", "ref"};
-    None defers to the pinned process default, then the cached backend probe
-    (``repro.kernels.kernel_mode``)."""
+    None defers to the cached backend probe (``repro.kernels.kernel_mode``)."""
     mode = kernels_mod.kernel_mode(force)
     if mode == "pallas":
         return embedding_bag_pallas(table, ids, weights, combiner)

@@ -3,10 +3,9 @@
 On TPU the Pallas kernel runs compiled; everywhere else (this CPU container, unit
 tests) we run either the kernel under ``interpret=True`` or the jnp oracle — both
 produce identical results. The default for library callers is the oracle path on
-CPU (fast to trace) and the kernel on TPU; both the backend probe and the default
-can be pinned process-wide (``repro.kernels.set_kernel_mode``, fed by
-``TrainerConfig.kernel_mode``) — the probe itself is cached, so dispatch inside
-jitted loops never re-walks the backend registry.
+CPU (fast to trace) and the kernel on TPU (``repro.kernels.kernel_mode``); the
+backend probe is cached, so dispatch inside jitted loops never re-walks the
+backend registry.
 """
 from __future__ import annotations
 
@@ -20,8 +19,7 @@ def gibbs_argmax(
     vocab_size: int, temperature: float = 1.0, *, force: str | None = None,
 ):
     """force in {None, "pallas", "interpret", "ref"}; None defers to the
-    pinned process default (``repro.kernels.set_kernel_mode``), then to the
-    cached backend probe."""
+    cached backend probe (``repro.kernels.kernel_mode``)."""
     mode = kernels_mod.kernel_mode(force)
     if mode == "pallas":
         return gibbs_argmax_pallas(

@@ -41,8 +41,6 @@ class TrainerConfig:
                                    # "dense" = exact [T, K] plane scan,
                                    # "alias" = sparsity-aware alias-table MH
     n_mh: int = 4                  # MH steps per token (alias sampler)
-    kernel_mode: Optional[str] = None  # pin kernel dispatch process-wide:
-                                   # None (auto) | pallas | interpret | ref
     # --------------------------------------------------------- schedule ----
     n_epochs: int = 20
     agg_every: int = 3             # aggregation boundary cadence (multi-pod)
@@ -93,10 +91,6 @@ class TrainerConfig:
                 f"{self.sampler!r}")
         if self.n_mh < 1:
             raise ValueError("TrainerConfig.n_mh must be >= 1")
-        if self.kernel_mode not in (None, "pallas", "interpret", "ref"):
-            raise ValueError(
-                "TrainerConfig.kernel_mode must be None, 'pallas', "
-                f"'interpret' or 'ref', got {self.kernel_mode!r}")
         if self.resume and self.ckpt_dir is None:
             raise ValueError("TrainerConfig.resume requires ckpt_dir")
         if self.n_model_shards < 1:

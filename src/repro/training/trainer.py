@@ -237,10 +237,6 @@ class Trainer:
         cfg = self.config
         src = self.source
         K, M = cfg.n_topics, cfg.ring_size
-        if cfg.kernel_mode is not None:
-            from repro import kernels as kernels_mod
-
-            kernels_mod.set_kernel_mode(cfg.kernel_mode)
         doc_cap = 0
         if cfg.sampler == "alias":
             from repro.core import sparse

@@ -168,35 +168,6 @@ def test_vmem_hbm_resident_table_is_free():
     assert plan.vmem_bytes < vmem.VMEM_BUDGET_BYTES
 
 
-def test_vmem_alias_whole_table_blocks_hit_capacity_cliff():
-    """kernels/alias/kernel.py binds whole [rows, K] planes in VMEM; the
-    planner must reproduce that capacity comment: rows·K small = fits,
-    rows·K ≳ 1M entries × 6 planes = budget error (the HBM-resident-table
-    work item this check unblocks)."""
-    from repro.kernels.alias import kernel as ak
-
-    sds = jax.ShapeDtypeStruct
-
-    def plans_at(rows, K):
-        return vmem.plan_fn(
-            lambda *a: vmem.unjitted(ak.mh_resample_pallas)(
-                *a, vocab_size=rows, n_mh=4),
-            sds((rows, K), jnp.int32), sds((K,), jnp.int32),
-            sds((64, 16), jnp.int32), sds((64, 16), jnp.int32),
-            sds((rows, K), jnp.float32), sds((rows, K), jnp.float32),
-            sds((rows, K), jnp.int32), sds((K,), jnp.float32),
-            sds((K,), jnp.float32), sds((K,), jnp.int32),
-            sds((64,), jnp.int32), sds((64,), jnp.int32),
-            sds((64,), jnp.int32), sds((64,), jnp.uint32),
-            sds((), jnp.uint32), sds((), jnp.float32),
-            sds((), jnp.float32))
-
-    ok = vmem.check_vmem(plans_at(256, 128))
-    assert all(f.severity == report.INFO for f in ok)
-    over = vmem.check_vmem(plans_at(2048, 1024))   # 2M entries × 6 planes
-    assert any(f.severity == report.ERROR for f in over)
-
-
 # --------------------------------------------------------------- lint -------
 
 

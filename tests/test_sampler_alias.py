@@ -150,5 +150,3 @@ def test_config_validates_sampler_fields():
         TrainerConfig(sampler="fancy")
     with pytest.raises(ValueError):
         TrainerConfig(sampler="alias", n_mh=0)
-    with pytest.raises(ValueError):
-        TrainerConfig(kernel_mode="maybe")

@@ -12,9 +12,7 @@ executed it. The suite covers:
   * the pure row-permutation algebra of ``training.reshard``;
   * ``collective_bytes`` recognizing the rotation's collective-permutes in
     compiled HLO (regression: rotation traffic must not be invisible to the
-    cost model), with trip-folded totals matching the §10 analytic model;
-  * by-word probe batching in ``kernels.alias.ops.mh_resample`` being a
-    bitwise-free reorder.
+    cost model), with trip-folded totals matching the §10 analytic model.
 
 Multi-device cases run in subprocesses (``conftest.run_with_devices``); the
 mesh is (data=4, model=P), so P=2 needs 8 host devices and P=4 needs 16.
@@ -295,39 +293,6 @@ def test_model_shard_report_paper_scale():
     # rotation traffic stays bounded (never worse than replicated here)
     assert (p8["rotation_bytes_per_epoch"]
             <= 1.5 * base["rotation_bytes_per_epoch"])
-
-
-def test_mh_by_word_batching_is_bitwise_free():
-    """Stable-sorting probes by word before dispatch must not change any
-    sampled z (uid-keyed counters; snapshot reads)."""
-    import jax.numpy as jnp
-
-    from repro.core import sparse
-    from repro.kernels.alias import ops
-
-    rng = np.random.default_rng(0)
-    R, K, T, Dn = 10, 12, 64, 16
-    phi = jnp.asarray(rng.integers(0, 9, (R, K)), jnp.int32)
-    psi = phi.sum(0)
-    alpha = jnp.asarray(rng.random(K), jnp.float32)
-    wq, wp, wa = sparse.make_word_tables(phi[None], psi, jnp.float32(0.01), R)
-    ap, aa = sparse.make_alpha_table(alpha)
-    dt = jnp.asarray(rng.integers(0, K, (Dn, 6)), jnp.int32)
-    dc = jnp.asarray(rng.integers(1, 4, (Dn, 6)), jnp.int32)
-    w = jnp.asarray(rng.integers(0, R, T), jnp.int32)
-    d = jnp.asarray(rng.integers(0, Dn, T), jnp.int32)
-    z = jnp.asarray(rng.integers(0, K, T), jnp.int32)
-    uid = jnp.asarray(rng.integers(0, 1 << 20, T), jnp.uint32)
-    outs = {}
-    for batch in (False, True):
-        for force in ("ref", "interpret"):
-            outs[(batch, force)] = np.asarray(ops.mh_resample(
-                phi, psi, dt, dc, wq[0], wp[0], wa[0], alpha, ap, aa,
-                w, d, z, uid, 7, jnp.float32(0.01), R, 4,
-                force=force, batch_by_word=batch))
-    ref = outs[(False, "ref")]
-    for k, v in outs.items():
-        assert (v == ref).all(), k
 
 
 def test_config_validation():

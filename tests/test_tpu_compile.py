@@ -96,8 +96,7 @@ def test_alias_build_compiles_at_chip_share(sds):
     """The build writes its permutations by row-batched sorts along K: no
     scatter, no sort inside a loop over rows, and temporaries within 5% of
     1.738 GB, those of a build by row loops of [K] scatters."""
-    c = alias_ops.build_alias.lower(sds((V, K), jnp.float32),
-                                   force=alias_ops.TPU_MODE).compile()
+    c = alias_ops.build_alias.lower(sds((V, K), jnp.float32)).compile()
     temp = _fits(c)
     hlo = c.as_text()
     assert "scatter(" not in hlo
@@ -194,7 +193,7 @@ def test_word_tables_built_per_shard_hold_no_gathered_phi(
 def test_alias_mh_resample_compiles_at_chip_share(sds):
     T, D, cap = 4096, 1024, 16
     f = jax.jit(alias_ops.mh_resample,
-                static_argnames=("vocab_size", "n_mh", "force"))
+                static_argnames=("vocab_size", "n_mh"))
     c = f.lower(
         sds((V, K), jnp.int32), sds((K,), jnp.int32),
         sds((D, cap), jnp.int32), sds((D, cap), jnp.int32),
@@ -203,7 +202,7 @@ def test_alias_mh_resample_compiles_at_chip_share(sds):
         sds((K,), jnp.float32), sds((K,), jnp.int32),
         sds((T,), jnp.int32), sds((T,), jnp.int32), sds((T,), jnp.int32),
         sds((T,), jnp.uint32), sds((), jnp.uint32), sds((), jnp.float32),
-        vocab_size=V, n_mh=4, force=alias_ops.TPU_MODE).compile()
+        vocab_size=V, n_mh=4).compile()
     _fits(c)
 
 
