@@ -24,10 +24,13 @@ Three vectorized primitives (no per-token host loops, all jit-safe):
 
 Table builders (:func:`make_word_tables`, :func:`make_alpha_table`) produce
 the stale proposal tables the MH probe corrects against; the Trainer rebuilds
-them at aggregation boundaries from merged Φ. Each table's Walker build is a
-program of its own name (``build_alias_word``, ``build_alias_alpha``), so a
-device trace tells the two apart. The word tables are built per shard, in
-Φ's own layout on the mesh: each chip builds the rows it holds.
+them at aggregation boundaries from merged Φ. Each build is a program of its
+own name (``build_alias_word``, ``build_alias_alpha``,
+``build_alias_alpha_prep``), so a device trace tells them apart. The word
+tables are built per shard, in Φ's own layout on the mesh: each chip builds
+the rows it holds. When both are due, α's row is normalized and partitioned
+in a program of its own (:func:`prepare_alpha_table`) and swept as one more
+row of the word build, on every chip.
 """
 from __future__ import annotations
 
@@ -36,6 +39,7 @@ from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.kernels.alias import ops as alias_ops
 
@@ -217,22 +221,27 @@ def apply_deltas(topic, count, d, z_old, z_new, valid):
 def _table_build(name: str):
     """``alias_ops.alias_tables`` as a program named ``name``: the traced
     function of ``alias_ops.build_alias``, so the same HLO, under a name of
-    its own.
+    its own. Given ``row``, a prepared row (``prepare_alpha_table``), it is
+    ``alias_ops.alias_tables_with``: that row's tables come out too.
 
     Given a ``mesh`` and the ``spec`` of the weights' layout on it, the
     build runs under ``shard_map`` with that spec in and out: each chip
-    normalises, partitions and sweeps only the rows it holds, and the
-    tables come out in the weights' layout. Without, it is one program
-    over the whole array, and the partitioner gathers every row of a
-    sharded input onto every chip (the row loops of the build cannot be
-    split)."""
-    def build(weights, *, mesh=None, spec=None):
+    normalises, partitions and sweeps only the rows it holds (and ``row``,
+    replicated), and the tables come out in the weights' layout. Without,
+    it is one program over the whole array, and the partitioner gathers
+    every row of a sharded input onto every chip (the row loops of the
+    build cannot be split)."""
+    def build(weights, row=None, *, mesh=None, spec=None):
+        if row is None:
+            fn, args = alias_ops.alias_tables, (weights,)
+            in_specs, out_specs = (spec,), (spec, spec)
+        else:
+            fn, args = alias_ops.alias_tables_with, (weights, row)
+            in_specs, out_specs = (spec, P()), (spec, spec, P(), P())
         if mesh is None:
-            return alias_ops.alias_tables(weights)
-        return jax.shard_map(alias_ops.alias_tables, mesh=mesh,
-                             in_specs=(spec,),
-                             out_specs=(spec, spec),
-                             check_vma=False)(weights)
+            return fn(*args)
+        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)(*args)
 
     build.__name__ = build.__qualname__ = name
     return jax.jit(build, static_argnames=("mesh", "spec"))
@@ -240,6 +249,14 @@ def _table_build(name: str):
 
 build_alias_word = _table_build("build_alias_word")
 build_alias_alpha = _table_build("build_alias_alpha")
+
+
+@jax.jit
+def build_alias_alpha_prep(weights):
+    """``alias_ops.prepare`` of α's [1, K] row, in a program whose input is
+    α alone: the row total rounds as in ``build_alias_alpha``, whatever
+    build its stream then joins."""
+    return alias_ops.prepare(weights)
 
 
 def _layout(x):
@@ -252,8 +269,8 @@ def _layout(x):
     return None, None
 
 
-def make_word_tables(phi, psi, beta,
-                     vocab_size: int) -> Tuple[jax.Array, ...]:
+def make_word_tables(phi, psi, beta, vocab_size: int,
+                     alpha_prep=None) -> Tuple[jax.Array, ...]:
     """Stale word-proposal tables from a Φ snapshot.
 
     phi [..., rows, K] int32, psi [..., K] int32 (leading pod/shard dims ride
@@ -261,6 +278,10 @@ def make_word_tables(phi, psi, beta,
     proposal including its denominator, so staleness covers both factors.
     A Φ laid out on a mesh is built per shard in that layout: each chip
     builds the tables of its own rows (``_table_build``).
+
+    Given ``alpha_prep`` (:func:`prepare_alpha_table`), α's row is swept with
+    the word rows (replicated on Φ's mesh), and the result is
+    (wq, wp, wa, ap, aa), ``(ap, aa)`` bit for bit :func:`make_alpha_table`'s.
     """
     mesh, spec = _layout(phi)
     beta = jnp.float32(beta)
@@ -269,21 +290,28 @@ def make_word_tables(phi, psi, beta,
         psi_b = jnp.expand_dims(psi_b, -2)
     wq = (phi.astype(jnp.float32) + beta) / (
         psi_b + jnp.float32(vocab_size) * beta)
-    wp, wa = build_alias_word(wq, mesh=mesh, spec=spec)
-    return wq, wp, wa
+    if alpha_prep is not None and mesh is not None:
+        alpha_prep = jax.device_put(alpha_prep, NamedSharding(mesh, P()))
+    return (wq,) + tuple(build_alias_word(wq, alpha_prep, mesh=mesh,
+                                          spec=spec))
+
+
+def prepare_alpha_table(alpha):
+    """α's row normalized and partitioned for the Walker sweep: the stream
+    :func:`make_word_tables` sweeps beside the word rows."""
+    return build_alias_alpha_prep(alpha[None, :].astype(jnp.float32))
 
 
 def make_alpha_table(alpha):
-    """α alias table (ap [K] f32, aa [K] int32) — rebuilt whenever the Minka
-    fixed point moves α (cheap: one K-row build)."""
+    """α alias table (ap [K] f32, aa [K] int32), built alone: for a rebuild
+    of α while the word tables stand (one row's sweep on its own)."""
     ap, aa = build_alias_alpha(alpha[None, :].astype(jnp.float32))
     return ap[0], aa[0]
 
 
 def make_tables(phi, psi, alpha, beta, vocab_size: int) -> AliasTables:
-    wq, wp, wa = make_word_tables(phi, psi, beta, vocab_size)
-    ap, aa = make_alpha_table(alpha)
-    return AliasTables(wq, wp, wa, ap, aa)
+    return AliasTables(*make_word_tables(phi, psi, beta, vocab_size,
+                                         prepare_alpha_table(alpha)))
 
 
 # ------------------------------------------------------------ block MH ------

@@ -88,6 +88,8 @@ class Trainer:
         self._tables = None          # alias sampler proposal tables (§9)
         self._tables_built_at = -1   # epoch of the last word-table rebuild
         self._tables_alpha = None    # the α the current α table was built from
+        # α table builds that rode a word build, and those built alone
+        self._alpha_builds = {"with_word": 0, "alone": 0}
         self._streaming = False
         self._ep_time = 0.0          # per-epoch accumulator (streaming)
         self._omega_from = None      # first epoch that folds Ω incrementally
@@ -468,8 +470,10 @@ class Trainer:
 
     def _rebuild_tables(self, word: bool = True) -> None:
         """Refresh the alias sampler's stale proposal state from the current
-        (phi, psi, α). ``word=False`` refreshes only the (cheap) α table —
-        used when α moved but Φ is mid-window."""
+        (phi, psi, α). ``word=False`` refreshes only the α table — used when
+        α moved but Φ is mid-window. With the word tables, α's row rides
+        their build: prepared in a program of its own, then swept and sorted
+        with the word rows (``sparse.make_word_tables``)."""
         import jax
 
         from repro.core import sparse
@@ -482,15 +486,20 @@ class Trainer:
             # first epoch replaced it
             phi, psi = jax.device_put(self.state[:2], self._epoch_in[:2])
             self.state = (phi, psi) + tuple(self.state[2:])
+            with spans.span("peacock.train.tables.alpha"):
+                alpha_prep = sparse.prepare_alpha_table(self.alpha)
             with spans.span("peacock.train.tables.word"):
                 # built per shard, in Φ's layout on the mesh
-                wq, wp, wa = sparse.make_word_tables(
-                    phi, psi, self.beta, self.ring_cfg.vocab_size)
+                tables = sparse.make_word_tables(
+                    phi, psi, self.beta, self.ring_cfg.vocab_size,
+                    alpha_prep)
+            self._alpha_builds["with_word"] += 1
         else:
-            wq, wp, wa = self._tables.wq, self._tables.wp, self._tables.wa
-        with spans.span("peacock.train.tables.alpha"):
-            ap, aa = sparse.make_alpha_table(self.alpha)
-        self._tables = sparse.AliasTables(wq, wp, wa, ap, aa)
+            with spans.span("peacock.train.tables.alpha"):
+                ap, aa = sparse.make_alpha_table(self.alpha)
+            tables = self._tables[:3] + (ap, aa)
+            self._alpha_builds["alone"] += 1
+        self._tables = sparse.AliasTables(*tables)
         self._tables_alpha = self.alpha
 
     def _epoch_tables(self) -> tuple:
@@ -761,6 +770,7 @@ class Trainer:
             "n_publishes": len(pub_s),
             "ll_final": ll[-1] if ll else None,
             "ring": self._ring_record(),
+            "alpha_tables": dict(self._alpha_builds),
             "spans": spans.recorder().totals(since=self._spans_from),
         }
 

@@ -25,10 +25,10 @@ RNG = np.random.default_rng(11)
 
 
 def _prepared(w):
-    """``_prepare``'s (order, wn_ord, ns) as NumPy arrays, with the
+    """``prepare``'s (order, wn_ord, ns) as NumPy arrays, with the
     normalized weights by slot, ``wn``, rebuilt exactly by inverting
     ``order`` over ``wn_ord``."""
-    order, wn_ord, ns = (np.asarray(a) for a in alias_ops._prepare(w))
+    order, wn_ord, ns = (np.asarray(a) for a in alias_ops.prepare(w))
     wn = np.empty_like(wn_ord)
     np.put_along_axis(wn, order, wn_ord, axis=-1)
     return wn, order, wn_ord, ns
@@ -141,7 +141,7 @@ def _swept(R, K, kind):
     alias) outputs for them, each [R, K]."""
     rng = np.random.default_rng(R * 7919 + K)
     w = jnp.asarray(_rows(kind, R, K, rng).astype(np.float32))
-    order, wn_ord, ns = alias_ops._prepare(w)
+    order, wn_ord, ns = alias_ops.prepare(w)
     return w, tuple(np.asarray(a) for a in alias_ops._sweep(order, wn_ord,
                                                             ns))
 
@@ -175,7 +175,7 @@ def test_sorted_table_write_matches_scatter_oracle(R, K, kind):
 
 @pytest.mark.parametrize("R,K", [(1, 1), (3, 40), (6, 1000)])
 def test_prepare_partition_is_the_stable_argsort(R, K):
-    """The prefix-sum partition in ``_prepare`` == a stable argsort of the
+    """The prefix-sum partition in ``prepare`` == a stable argsort of the
     small/large flags (smalls in index order, then larges)."""
     w = jnp.asarray(RNG.gamma(0.3, 1.0, (R, K)).astype(np.float32))
     w = w.at[0, :K // 2].set(0.0)          # zero-weight slots are smalls
@@ -191,7 +191,7 @@ def test_prepare_stream_weights_follow_order(R, K):
     """``wn_ord`` from the ordering sort == the mean-1 weights gathered by
     ``order``."""
     w = jnp.asarray(RNG.gamma(0.3, 1.0, (R, K)).astype(np.float32))
-    order, wn_ord, _ = (np.asarray(a) for a in alias_ops._prepare(w))
+    order, wn_ord, _ = (np.asarray(a) for a in alias_ops.prepare(w))
     wn = np.asarray(w * (jnp.float32(K) / jnp.maximum(
         jnp.sum(w, axis=-1, keepdims=True), jnp.float32(1e-30))))
     np.testing.assert_array_equal(
@@ -215,6 +215,37 @@ def test_alias_invariant_reconstructs_distribution(shape):
     np.testing.assert_allclose(rec, wn, atol=2e-5, rtol=1e-5)
     assert (p >= 0).all() and (p <= 1).all()
     assert ((a >= 0) & (a < K)).all()
+
+
+def _alpha_row(kind, K, rng):
+    """[1, K] α-like weights; ``boundary`` sums to exactly K, so its
+    normalized weights are the weights themselves: half-integers below,
+    at and above the mean 1."""
+    if kind != "boundary":
+        return _rows(kind, 1, K, rng)
+    w = np.ones((1, K))
+    n = K // 4
+    w[0, 0:2 * n:2], w[0, 1:2 * n:2] = 0.5, 1.5
+    return rng.permuted(w, axis=1)
+
+
+@pytest.mark.parametrize("kind", ["gamma", "equal", "onehot", "zero",
+                                  "boundary"])
+@pytest.mark.parametrize("R,K", [(1, 37), (5, 128), (64, 129), (3, 1000)])
+def test_row_swept_with_the_word_build_keeps_both_tables(R, K, kind):
+    """α's row prepared in a program of its own and swept as row R of the
+    word build (``build_alias_word(w, build_alias_alpha_prep(a))``): the
+    word tables are ``build_alias`` of the word rows and α's are
+    ``build_alias`` of α's row alone, bit for bit."""
+    rng = np.random.default_rng(R * 31 + K)
+    w = jnp.asarray(_rows("gamma", R, K, rng).astype(np.float32))
+    a = jnp.asarray(_alpha_row(kind, K, rng).astype(np.float32))
+    wp, wa, ap, aa = sparse.build_alias_word(
+        w, sparse.build_alias_alpha_prep(a))
+    for got, want in zip((wp, wa, ap[None], aa[None]),
+                         alias_ops.build_alias(w) + alias_ops.build_alias(a)):
+        assert got.dtype == want.dtype
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_alias_build_degenerate_rows():

@@ -3,7 +3,9 @@ epoch against the plain round-by-round reference (``core/ring_reference``).
 
 * ``build_alias_word`` under ``shard_map`` over a Φ in the 4×1 ring layout
   and in the 2×2 word-sharded layout gives the tables of one build over the
-  whole Φ, bit for bit, in Φ's layout;
+  whole Φ, bit for bit, in Φ's layout; with α's row swept beside each
+  chip's rows, α's table on every chip is the one-row build's, and the
+  Trainer counts which α builds rode a word build and which ran alone;
 * the Trainer's ring epoch at M = 4 draws what the reference draws, token
   for token, over epochs with the tables rebuilt between them; a draw that
   differs counts only where the reference shows it to be a tie;
@@ -52,6 +54,76 @@ for name, grid, shape, spec in [
 def test_word_tables_built_per_shard_equal_one_build(subproc):
     out = subproc(TABLES_CODE, n_devices=4, timeout=600)
     assert out.count("TABLES_OK") == 2, out
+
+
+ALPHA_CODE = """
+import numpy as np, jax, jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding
+from repro.core import sparse
+from repro.dist import sharding as shd
+from repro.training import Trainer, TrainerConfig
+
+rng = np.random.default_rng(4)
+K, R, V = 300, 7, 500
+alpha = jnp.asarray(rng.gamma(0.3, 1.0, K).astype(np.float32))
+alone = [np.asarray(t) for t in sparse.make_alpha_table(alpha)]
+for name, grid, shape, spec in [
+        ("ring", (4, 1), (4, R, K), shd.ring_spec()),
+        ("word_sharded", (2, 2), (2, 2 * R, K), shd.wshard_spec())]:
+    mesh = Mesh(np.array(jax.devices()).reshape(grid), shd.RING_AXES)
+    phi = (rng.integers(0, 6, shape) * (rng.random(shape) < 0.2)).astype(
+        np.int32)
+    psi = jnp.asarray(phi.reshape(-1, K).sum(0).astype(np.int32))
+    sharding = NamedSharding(mesh, spec)
+    phi = jax.device_put(phi, sharding)
+    words = sparse.make_word_tables(phi, psi, 0.01, V)
+    both = sparse.make_word_tables(phi, psi, 0.01, V,
+                                   sparse.prepare_alpha_table(alpha))
+    assert len(both) == 5, name
+    for a, b in zip(words, both):
+        assert b.sharding.is_equivalent_to(sharding, b.ndim), name
+        assert np.array_equal(np.asarray(a), np.asarray(b)), name
+    for want, got in zip(alone, both[3:]):
+        assert got.sharding.is_fully_replicated, name
+        assert len(got.addressable_shards) == 4, name
+        for sh in got.addressable_shards:
+            assert np.array_equal(np.asarray(sh.data), want), name
+    print(name + ":ALPHA_OK")
+
+cfg = TrainerConfig(
+    n_docs=400, vocab_size=120, n_topics=32, true_topics=8, doc_len_mean=6,
+    data_shards=4, model_shards=1, n_epochs=1, agg_every=1,
+    alpha_opt_from=100, sampler="alias", n_mh=4, seed=5, bench_out=None)
+tr = Trainer(cfg).setup()
+
+def separate():
+    phi, psi = tr.state[:2]
+    return (sparse.make_word_tables(phi, psi, tr.beta, cfg.vocab_size)
+            + sparse.make_alpha_table(tr.alpha))
+
+def same(tables, want):
+    return all(np.array_equal(np.asarray(a), np.asarray(b))
+               for a, b in zip(tables, want))
+
+tr._rebuild_tables()
+assert tr.bench_record()["alpha_tables"] == {"with_word": 1, "alone": 0}
+assert same(tr._tables, separate())
+words = tuple(tr._tables[:3])
+tr.alpha = tr.alpha * jnp.linspace(0.5, 2.0, cfg.n_topics)
+tr._rebuild_tables(word=False)
+assert tr.bench_record()["alpha_tables"] == {"with_word": 1, "alone": 1}
+assert tr._tables[:3] == words
+assert same(tr._tables, separate())
+tr._rebuild_tables()
+assert tr.bench_record()["alpha_tables"] == {"with_word": 2, "alone": 1}
+assert same(tr._tables, separate())
+print("TRAINER_OK")
+"""
+
+
+def test_alpha_table_rides_the_per_shard_word_build(subproc):
+    out = subproc(ALPHA_CODE, n_devices=4, timeout=600)
+    assert out.count("ALPHA_OK") == 2 and "TRAINER_OK" in out, out
 
 
 RING_CODE = """
